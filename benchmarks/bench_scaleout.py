@@ -8,7 +8,6 @@ level throughput — its cost is amortized by S1 convergence).
 from __future__ import annotations
 
 from repro.core import GB, PAPER_MODELS, run_workload, training_trace
-from repro.utils.roofline import PEAK_FLOPS  # noqa: F401  (doc cross-ref)
 
 from .common import A100_EFFECTIVE_FLOPS, CUMALLOC_SECONDS, Row, emit, timed
 

@@ -7,7 +7,7 @@ import json
 from pathlib import Path
 from typing import List, Optional
 
-from repro.utils.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+from repro.utils.roofline import DRYRUN_DEVICE_KIND, peaks_for
 
 from .common import Row, emit
 
@@ -23,9 +23,10 @@ def load_records(mesh: str) -> List[dict]:
 
 def markdown_table(mesh: str = "pod16x16") -> str:
     recs = load_records(mesh)
+    pk = peaks_for(DRYRUN_DEVICE_KIND)
     lines = [
-        f"### Roofline — {mesh} (v5e: {PEAK_FLOPS/1e12:.0f} TF/s, "
-        f"{HBM_BW/1e9:.0f} GB/s HBM, {ICI_BW/1e9:.0f} GB/s ICI)",
+        f"### Roofline — {mesh} ({DRYRUN_DEVICE_KIND}: {pk.flops/1e12:.0f} TF/s, "
+        f"{pk.hbm_bw/1e9:.0f} GB/s HBM, {pk.ici_bw/1e9:.0f} GB/s ICI)",
         "",
         "| arch | shape | kind | t_compute (s) | t_memory (s) | t_collective (s) "
         "| bottleneck | MODEL/HLO flops | roofline frac | mem/dev (GB) |",

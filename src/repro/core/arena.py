@@ -26,22 +26,43 @@ from ..kernels import ops
 from .trace import TraceRecorder
 
 
+#: lanes of one TPU vreg row; every chunk's minor dimension is a multiple
+LANE = 128
+
+
 @dataclass(frozen=True)
 class ArenaConfig:
     n_chunks: int
     dtype: jnp.dtype = jnp.bfloat16
     #: interpret=True runs the Pallas kernels in Python (CPU validation)
     interpret: bool = False
-    #: fall back to pure-jnp reference ops (no Pallas at all)
+    #: run the pure-jnp reference ops instead of the kernels
     use_reference_ops: bool = False
+    #: device shape of one chunk, ``(rows, lanes)`` with lanes a multiple of
+    #: 128. Default: the whole 2 MB lane-dense. A token-structured tenant
+    #: (the KV cache) passes its own; the chunk's unused tail (CHUNK_SIZE
+    #: minus rows*lanes*itemsize bytes) is never materialised on device.
+    chunk_shape: Optional[Tuple[int, int]] = None
 
     @property
     def itemsize(self) -> int:
         return jnp.dtype(self.dtype).itemsize
 
     @property
+    def chunk_dims(self) -> Tuple[int, int]:
+        if self.chunk_shape is None:
+            return (CHUNK_SIZE // self.itemsize // LANE, LANE)
+        rows, lanes = self.chunk_shape
+        if lanes % LANE or rows * lanes * self.itemsize > CHUNK_SIZE:
+            raise ValueError(f"chunk shape {self.chunk_shape} is not lane-dense "
+                             f"within {CHUNK_SIZE} bytes")
+        return (rows, lanes)
+
+    @property
     def chunk_elems(self) -> int:
-        return CHUNK_SIZE // self.itemsize
+        """Usable elements per chunk."""
+        rows, lanes = self.chunk_dims
+        return rows * lanes
 
     @property
     def capacity_bytes(self) -> int:
@@ -72,14 +93,15 @@ class Arena:
             self.device_model = allocator.device
             self.allocator = allocator
         self.recorder = recorder
-        self.buf = jnp.zeros((config.n_chunks, config.chunk_elems), config.dtype)
+        self.buf = jnp.zeros((config.n_chunks,) + config.chunk_dims, config.dtype)
         self._trace_ids: Dict[int, int] = {}
 
     # ------------------------------------------------------------------
     # allocation (host metadata only)
     # ------------------------------------------------------------------
     def alloc_elems(self, n_elems: int, label: str = "") -> Allocation:
-        nbytes = int(n_elems) * self.config.itemsize
+        # bytes the elements occupy, counting each chunk's unused tail
+        nbytes = -(-int(n_elems) * CHUNK_SIZE // self.config.chunk_elems)
         alloc = self.allocator.malloc(max(nbytes, CHUNK_SIZE))
         if self.recorder is not None:
             self._trace_ids[id(alloc)] = self.recorder.alloc(alloc.req_size, label)
@@ -129,7 +151,8 @@ class Arena:
         if pad:
             flat = jnp.pad(flat, (0, pad))
         _, scatter = self._ops()
-        self.buf = scatter(self.buf, cmap[:n_chunks], flat.reshape(n_chunks, c.chunk_elems))
+        self.buf = scatter(self.buf, cmap[:n_chunks],
+                           flat.reshape((n_chunks,) + c.chunk_dims))
 
     def load(self, alloc: Allocation, shape: Tuple[int, ...], dtype=None) -> jax.Array:
         """Read a logical tensor back out of the allocation's chunks."""

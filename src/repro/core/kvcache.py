@@ -7,14 +7,20 @@ allocations that grow geometrically), so the page table stays short and the
 attention kernel walks long physically-contiguous extents — fewer, larger
 DMAs on TPU.
 
-Token layout: one 2 MB chunk holds ``chunk_tokens = CHUNK_SIZE //
-(n_kv * head_dim * itemsize)`` tokens of K (or V) for ONE layer. K and V of
-every layer share the single arena (one memory lake), each with its own
+Token layout: one 2 MB chunk holds ``chunk_tokens`` tokens of K (or V) for
+ONE layer, packed in order into a lane-dense ``(chunk_rows, row_lanes)``
+slab: ``tokens_per_row`` token rows of ``n_kv * head_dim`` elements share a
+lane row, so ``row_lanes`` is a multiple of 128 and any head geometry tiles
+without padding (smollm-135m's 3x64 and h2o-danube-3-4b's 8x120 rows do not
+divide 2 MB). The few bytes a chunk cannot fit stay unused. K and V of every
+layer share the single arena (one memory lake), each with its own
 allocation per sequence.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -25,7 +31,8 @@ import numpy as np
 from ..alloc.caching_allocator import Allocation
 from ..alloc.chunks import CHUNK_SIZE
 from ..kernels import ops
-from .arena import Arena, ArenaConfig
+from ..kernels.stitched_attention import sublanes
+from .arena import LANE, Arena, ArenaConfig
 from .trace import TraceRecorder
 
 
@@ -46,14 +53,36 @@ class KVCacheConfig:
         return jnp.dtype(self.dtype).itemsize
 
     @property
+    def token_elems(self) -> int:
+        return self.n_kv * self.head_dim
+
+    @property
     def token_bytes(self) -> int:
-        return self.n_kv * self.head_dim * self.itemsize
+        return self.token_elems * self.itemsize
+
+    @property
+    def tokens_per_row(self) -> int:
+        return LANE // math.gcd(self.token_elems, LANE)
+
+    @property
+    def row_lanes(self) -> int:
+        return self.tokens_per_row * self.token_elems
+
+    @property
+    def chunk_rows(self) -> int:
+        """Lane rows per chunk: whole sublane tiles within CHUNK_SIZE."""
+        tile = sublanes(self.dtype)
+        rows = CHUNK_SIZE // (self.row_lanes * self.itemsize) // tile * tile
+        if rows == 0:
+            raise ValueError(
+                f"{self.tokens_per_row} x {self.n_kv}x{self.head_dim} token rows "
+                f"times {tile} sublanes do not fit one {CHUNK_SIZE}-byte chunk"
+            )
+        return rows
 
     @property
     def chunk_tokens(self) -> int:
-        ct = CHUNK_SIZE // self.token_bytes
-        assert ct > 0, "a KV token row must fit in one chunk"
-        return ct
+        return self.chunk_rows * self.tokens_per_row
 
 
 @dataclass
@@ -83,6 +112,7 @@ class StitchedKVCache:
                 dtype=config.dtype,
                 interpret=config.interpret,
                 use_reference_ops=config.use_reference_ops,
+                chunk_shape=(config.chunk_rows, config.row_lanes),
             ),
             allocator=allocator,
             recorder=recorder,
@@ -121,12 +151,12 @@ class StitchedKVCache:
         have_chunks = state.capacity_tokens // c.chunk_tokens
         if need_chunks <= have_chunks:
             return
-        delta = (need_chunks - have_chunks) * CHUNK_SIZE
+        delta = (need_chunks - have_chunks) * self.arena.config.chunk_elems
         for layer in range(c.n_layers):
             for kv in ("k", "v"):
                 key = (layer, kv)
                 state.allocs.setdefault(key, []).append(
-                    self.arena.alloc_elems(delta // c.itemsize, f"kv.{kv}.L{layer}")
+                    self.arena.alloc_elems(delta, f"kv.{kv}.L{layer}")
                 )
         state.capacity_tokens = need_chunks * c.chunk_tokens
 
@@ -154,32 +184,21 @@ class StitchedKVCache:
         lens = np.array([self.seqs[s].length for s in seq_ids], np.int32)
         return jnp.asarray(table), jnp.asarray(lens)
 
-    def arena_view(self) -> jax.Array:
-        """The arena buffer viewed token-structured for the attention kernel."""
-        c = self.config
-        return self.arena.buf.reshape(c.n_chunks, c.chunk_tokens, c.n_kv, c.head_dim)
-
     def write_tokens(
         self, seq_id: int, layer: int, kv: str, start: int, tokens: jax.Array
     ) -> None:
         """Write ``tokens`` (T, KVH, D) at logical position ``start``."""
         c = self.config
-        chunks = self._extent_chunks(seq_id, layer, kv)
-        buf = self.arena_view()
-        t = tokens.astype(c.dtype)
-        # split the logical token range on chunk boundaries, one DUS per run
-        pos = start
-        off = 0
-        while off < t.shape[0]:
-            chunk_idx = pos // c.chunk_tokens
-            in_chunk = pos % c.chunk_tokens
-            run = min(t.shape[0] - off, c.chunk_tokens - in_chunk)
-            buf = jax.lax.dynamic_update_slice(
-                buf, t[off : off + run][None], (chunks[chunk_idx], in_chunk, 0, 0)
-            )
-            pos += run
-            off += run
-        self.arena.buf = buf.reshape(self.arena.buf.shape)
+        chunks = np.asarray(self._extent_chunks(seq_id, layer, kv), np.int32)
+        pos = start + np.arange(tokens.shape[0])
+        in_chunk = pos % c.chunk_tokens
+        self.arena.buf = _scatter_token_rows(
+            self.arena.buf,
+            jnp.asarray(chunks[pos // c.chunk_tokens]),
+            jnp.asarray((in_chunk // c.tokens_per_row).astype(np.int32)),
+            jnp.asarray((in_chunk % c.tokens_per_row * c.token_elems).astype(np.int32)),
+            tokens.reshape(tokens.shape[0], c.token_elems).astype(c.dtype),
+        )
 
     def decode_attention(self, seq_ids: List[int], layer: int, q: jax.Array) -> jax.Array:
         """q: (B, H, D) one token per sequence -> (B, H, D).
@@ -189,13 +208,20 @@ class StitchedKVCache:
         c = self.config
         ptk, lens = self.page_table(seq_ids, layer, "k")
         ptv, _ = self.page_table(seq_ids, layer, "v", pad_chunks=ptk.shape[1])
-        view = self.arena_view()
+        buf = self.arena.buf
         if c.use_reference_ops:
-            return ops.decode_attention_ref(q, view, view, ptk, lens, ptv)
+            return ops.decode_attention_ref(q, buf, buf, ptk, lens, ptv, n_kv=c.n_kv)
         return ops.decode_attention(
-            q, view, view, ptk, lens, ptv, interpret=c.interpret
+            q, buf, buf, ptk, lens, ptv, n_kv=c.n_kv, interpret=c.interpret
         )
 
     # ------------------------------------------------------------------
     def utilization(self) -> float:
         return self.arena.utilization
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_token_rows(buf, chunk, row, lane0, vals):
+    """buf[chunk[t], row[t], lane0[t]:lane0[t] + E] = vals[t] for every t."""
+    lanes = lane0[:, None] + jnp.arange(vals.shape[1], dtype=jnp.int32)[None, :]
+    return buf.at[chunk[:, None], row[:, None], lanes].set(vals)

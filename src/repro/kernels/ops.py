@@ -41,23 +41,26 @@ def gather(arena, chunk_map, *, interpret: bool = False):
     return stitch_gather(arena, chunk_map, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+# the arena is donated: the kernel writes it in place
+@functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
 def scatter(arena, chunk_map, values, *, interpret: bool = False):
     return stitch_scatter(arena, chunk_map, values, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("n_kv", "interpret"))
 def decode_attention(
-    q, k_arena, v_arena, page_table, seq_lens, page_table_v=None, *, interpret: bool = False
+    q, k_arena, v_arena, page_table, seq_lens, page_table_v=None, *,
+    n_kv: int, interpret: bool = False,
 ):
     return stitched_decode_attention(
         q, k_arena, v_arena, page_table, seq_lens,
-        page_table_v=page_table_v, interpret=interpret,
+        n_kv=n_kv, page_table_v=page_table_v, interpret=interpret,
     )
 
 
-# reference implementations (jit'd) for benchmarking and fallback on hosts
-# where even interpret mode is undesirable
+# reference implementations (jit'd): the oracles the kernels are checked
+# against, in tests and in the chip smoke
 gather_ref = jax.jit(stitch_gather_ref)
 scatter_ref = jax.jit(stitch_scatter_ref)
-decode_attention_ref = jax.jit(stitched_decode_attention_ref)
+decode_attention_ref = jax.jit(stitched_decode_attention_ref,
+                               static_argnames=("n_kv",))

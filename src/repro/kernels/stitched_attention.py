@@ -7,13 +7,23 @@ per sequence, reading K/V straight out of the arena through the per-sequence
 page table (no gather materialisation), with the numerically-stable
 flash-decoding running max/sum accumulated across chunks in VMEM scratch.
 
-Layout: the arena is token-structured, ``(n_phys_chunks, T_c, KVH, D)``
-(T_c tokens per 2 MB chunk). Grid = (batch, chunks-per-seq); the chunk axis
-is minor, so scratch carries (m, l, acc) across a sequence's chunks and the
-output block is written once on the last chunk.
+Layout: the arena is lane-dense, ``(n_phys_chunks, R, W)``. A token row of
+``KVH * D`` elements is packed ``g = W // (KVH * D)`` to a lane row, so
+``W`` is a multiple of 128 and no tile is padded in HBM or VMEM; a chunk
+holds ``R * g`` tokens in order (``core/kvcache.py`` picks ``R`` and ``W``).
+Grid = (batch, chunks-per-seq, sub-blocks-per-chunk). Each step pulls an
+``(R_s, W)`` sub-block of K and of V, sized so that both double buffers fit
+the scoped VMEM. The chunk and sub-block axes are minor, so scratch carries
+(m, l, acc) across a sequence's tokens. Sub-blocks past the sequence length
+map to the last valid one, so the pipeline issues no DMA for them.
 
-GQA handled natively: q heads are grouped ``(KVH, G, D)`` so scores are a
-batched matmul over kv-heads — MXU-shaped, no head replication in memory.
+Scores come from one MXU matmul per sub-block against a block-diagonal
+query: row ``(j, h)`` holds head ``h``'s query under lane slot ``j`` and
+kv-head ``h // G``, zero elsewhere. So ``s[(j, h), r]`` is head ``h``'s score
+for token ``r * g + j``, with bf16 operands and f32 accumulation and no
+lane slicing in the kernel. Each ``(j, h)`` row is its own online-softmax
+stream; the wrapper merges the ``g`` streams of a head and keeps the
+diagonal ``(slot j, kv-head)`` block of each accumulator row.
 """
 
 from __future__ import annotations
@@ -26,6 +36,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(-1e30)
+#: VMEM bytes per K (or V) sub-block; K+V double-buffered is 4x this
+SUB_BLOCK_BYTES = 1 << 20
+
+
+def sublanes(dtype) -> int:
+    """Rows of one native (sublane, 128) tile: 8 for 32-bit, 16 for bf16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def sub_block_rows(rows: int, lanes: int, dtype) -> int:
+    """Largest divisor of ``rows`` that is a whole number of sublane tiles
+    and keeps one (rows, lanes) sub-block within SUB_BLOCK_BYTES."""
+    tile = sublanes(dtype)
+    if rows % tile:
+        raise ValueError(f"chunk rows {rows} are not a multiple of {tile}")
+    cap = max(tile, SUB_BLOCK_BYTES // (lanes * jnp.dtype(dtype).itemsize))
+    return max(r for r in range(tile, min(rows, cap) + 1, tile) if rows % r == 0)
 
 
 def _decode_attn_kernel(
@@ -34,71 +61,77 @@ def _decode_attn_kernel(
     page_table_v_ref,  # (B, C) int32
     seq_lens_ref,  # (B,) int32
     # inputs
-    q_ref,  # (1, KVH, G, D)
-    k_ref,  # (1, T_c, KVH, D)
-    v_ref,  # (1, T_c, KVH, D)
+    q_ref,  # (1, g*H, W) block-diagonal query
+    k_ref,  # (R_s, W)
+    v_ref,  # (R_s, W)
     # outputs
-    o_ref,  # (1, KVH, G, D)
+    acc_out_ref,  # (1, g*H, W) f32
+    m_out_ref,  # (1, g*H, 1) f32
+    l_out_ref,  # (1, g*H, 1) f32
     # scratch
-    m_ref,  # (KVH, G) f32
-    l_ref,  # (KVH, G) f32
-    acc_ref,  # (KVH, G, D) f32
+    m_ref,  # (g*H, 1) f32
+    l_ref,  # (g*H, 1) f32
+    acc_ref,  # (g*H, W) f32
     *,
-    chunk_tokens: int,
-    n_chunks: int,
+    n_heads: int,
+    slots: int,
+    sub_rows: int,
 ):
-    b = pl.program_id(0)
-    c = pl.program_id(1)
+    b, c, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_sub = pl.num_programs(2)
     seq_len = seq_lens_ref[b]
+    first_tok = (c * n_sub + s) * (sub_rows * slots)
 
-    @pl.when(c == 0)
+    @pl.when((c == 0) & (s == 0))
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # positions covered by this chunk; mask beyond the sequence length
-    base = c * chunk_tokens
-    pos = base + jax.lax.broadcasted_iota(jnp.int32, (chunk_tokens,), 0)
-    valid = pos < seq_len
-
-    @pl.when(base < seq_len)
+    @pl.when(first_tok < seq_len)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)  # (KVH, G, D)
-        k = k_ref[0].astype(jnp.float32)  # (T_c, KVH, D)
-        v = v_ref[0].astype(jnp.float32)  # (T_c, KVH, D)
-        # scores: batched over kv heads -> (KVH, G, T_c)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        )
-        s = jnp.where(valid[None, None, :], s, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
+        rows = slots * n_heads
+        # token of score (i, r) is first_tok + r * slots + (i // n_heads)
+        row_i = jax.lax.broadcasted_iota(jnp.int32, (rows, sub_rows), 0)
+        slot = jnp.zeros_like(row_i)
+        for j in range(1, slots):
+            slot = slot + (row_i >= j * n_heads).astype(jnp.int32)
+        pos = (first_tok + slot
+               + slots * jax.lax.broadcasted_iota(jnp.int32, (rows, sub_rows), 1))
+        valid = pos < seq_len
+        k = k_ref[...]
+        s_ = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # (g*H, R_s)
+        s_ = jnp.where(valid, s_, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[..., None])  # (KVH, G, T_c)
-        p = jnp.where(valid[None, None, :], p, 0.0)
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1)
+        p = jnp.where(valid, jnp.exp(s_ - m_new), 0.0)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[...]
         pv = jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
-        )  # (KVH, G, D)
-        acc_ref[...] = alpha[..., None] * acc_ref[...] + pv
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (g*H, W)
+        acc_ref[...] = alpha * acc_ref[...] + pv
         m_ref[...] = m_new
 
-    @pl.when(c == n_chunks - 1)
+    @pl.when((c == pl.num_programs(1) - 1) & (s == n_sub - 1))
     def _finalize():
-        l = l_ref[...]
-        safe_l = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_ref[...] / safe_l[..., None]).astype(o_ref.dtype)
+        acc_out_ref[0] = acc_ref[...]
+        m_out_ref[0] = m_ref[...]
+        l_out_ref[0] = l_ref[...]
 
 
 def stitched_decode_attention(
     q: jax.Array,  # (B, H, D)
-    k_arena: jax.Array,  # (n_phys, T_c, KVH, D)
-    v_arena: jax.Array,  # (n_phys, T_c, KVH, D)
+    k_arena: jax.Array,  # (n_phys, R, W)
+    v_arena: jax.Array,  # (n_phys, R, W)
     page_table: jax.Array,  # (B, C) int32, physical chunk per logical chunk
     seq_lens: jax.Array,  # (B,) int32
     *,
+    n_kv: int,
     page_table_v: jax.Array | None = None,  # defaults to sharing page_table
     scale: float | None = None,
     interpret: bool = False,
@@ -110,50 +143,93 @@ def stitched_decode_attention(
     one shared table.
     """
     batch, n_heads, head_dim = q.shape
-    n_phys, chunk_tokens, n_kv, head_dim_k = k_arena.shape
-    assert head_dim == head_dim_k and v_arena.shape == k_arena.shape
-    assert n_heads % n_kv == 0, f"GQA needs H % KVH == 0, got {n_heads} % {n_kv}"
+    _, rows, lanes = k_arena.shape
+    token_elems = n_kv * head_dim
+    if lanes % token_elems or v_arena.shape != k_arena.shape:
+        raise ValueError(
+            f"arena {k_arena.shape}/{v_arena.shape} does not hold "
+            f"{n_kv}x{head_dim} token rows"
+        )
+    if n_heads % n_kv:
+        raise ValueError(f"GQA needs H % KVH == 0, got {n_heads} % {n_kv}")
+    slots = lanes // token_elems
     group = n_heads // n_kv
     n_chunks = page_table.shape[1]
-    assert page_table.shape == (batch, n_chunks)
     if page_table_v is None:
         page_table_v = page_table
-    assert page_table_v.shape == page_table.shape
+    if page_table.shape != (batch, n_chunks) or page_table_v.shape != page_table.shape:
+        raise ValueError(f"page tables {page_table.shape}/{page_table_v.shape} "
+                         f"are not (batch={batch}, chunks)")
+    sub_rows = sub_block_rows(rows, lanes, k_arena.dtype)
+    n_sub = rows // sub_rows
+    sub_tokens = sub_rows * slots
 
     scale = (head_dim**-0.5) if scale is None else scale
-    q4 = (q * scale).reshape(batch, n_kv, group, head_dim)
+    # block-diagonal query (B, g*H, W): row (j, k, h) carries q[k*G + h]
+    # at lanes j*KVH*D + k*D ... + D, zeros elsewhere
+    q4 = (q.astype(jnp.float32) * scale).reshape(batch, n_kv, group, head_dim)
+    q_bd = jnp.einsum(
+        "bkhd,ji,kl->bjkhild", q4,
+        jnp.eye(slots, dtype=jnp.float32), jnp.eye(n_kv, dtype=jnp.float32),
+    ).reshape(batch, slots * n_heads, lanes).astype(k_arena.dtype)
 
+    def kv_block(b, c, s, pt, sl):
+        # clamp sub-blocks past the sequence to the last valid one: an
+        # unchanged block index makes the pipeline skip the DMA
+        last = jnp.maximum(sl[b] - 1, 0) // sub_tokens
+        j = jnp.minimum(c * n_sub + s, last)
+        return pt[b, j // n_sub], j % n_sub, 0
+
+    rows_q = slots * n_heads
+    per_seq = lambda b, c, s, ptk, ptv, sl: (b, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(batch, n_chunks),
+        grid=(batch, n_chunks, n_sub),
         in_specs=[
-            pl.BlockSpec(
-                (1, n_kv, group, head_dim), lambda b, c, ptk, ptv, sl: (b, 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, chunk_tokens, n_kv, head_dim),
-                lambda b, c, ptk, ptv, sl: (ptk[b, c], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, chunk_tokens, n_kv, head_dim),
-                lambda b, c, ptk, ptv, sl: (ptv[b, c], 0, 0, 0),
-            ),
+            pl.BlockSpec((1, rows_q, lanes), per_seq),
+            pl.BlockSpec((None, sub_rows, lanes),
+                         lambda b, c, s, ptk, ptv, sl: kv_block(b, c, s, ptk, sl)),
+            pl.BlockSpec((None, sub_rows, lanes),
+                         lambda b, c, s, ptk, ptv, sl: kv_block(b, c, s, ptv, sl)),
         ],
-        out_specs=pl.BlockSpec(
-            (1, n_kv, group, head_dim), lambda b, c, ptk, ptv, sl: (b, 0, 0, 0)
-        ),
+        out_specs=[
+            pl.BlockSpec((1, rows_q, lanes), per_seq),
+            pl.BlockSpec((1, rows_q, 1), per_seq),
+            pl.BlockSpec((1, rows_q, 1), per_seq),
+        ],
         scratch_shapes=[
-            pltpu.VMEM((n_kv, group), jnp.float32),
-            pltpu.VMEM((n_kv, group), jnp.float32),
-            pltpu.VMEM((n_kv, group, head_dim), jnp.float32),
+            pltpu.VMEM((rows_q, 1), jnp.float32),
+            pltpu.VMEM((rows_q, 1), jnp.float32),
+            pltpu.VMEM((rows_q, lanes), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(
-            _decode_attn_kernel, chunk_tokens=chunk_tokens, n_chunks=n_chunks
-        ),
+    acc, m, l = pl.pallas_call(
+        functools.partial(_decode_attn_kernel, n_heads=n_heads, slots=slots,
+                          sub_rows=sub_rows),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, n_kv, group, head_dim), q.dtype),
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, rows_q, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((batch, rows_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((batch, rows_q, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
         interpret=interpret,
-    )(page_table, page_table_v, seq_lens, q4, k_arena, v_arena)
-    return out.reshape(batch, n_heads, head_dim)
+        name="stitched_decode_attention",
+    )(page_table, page_table_v, seq_lens, q_bd, k_arena, v_arena)
+
+    # keep each row's own (slot j, kv-head) block, then merge the g
+    # per-slot softmax streams of every head
+    acc = acc.reshape(batch, slots, n_kv, group, slots, n_kv, head_dim)
+    acc = jnp.einsum("bjkhild,ji,kl->bjkhd", acc,
+                     jnp.eye(slots, dtype=acc.dtype), jnp.eye(n_kv, dtype=acc.dtype))
+    acc = acc.reshape(batch, slots, n_heads, head_dim)
+    m = m.reshape(batch, slots, n_heads)
+    l = l.reshape(batch, slots, n_heads)
+    m_all = jnp.max(m, axis=1, keepdims=True)
+    w = jnp.exp(m - m_all)
+    l_all = jnp.sum(l * w, axis=1)
+    o = jnp.sum(acc * w[..., None], axis=1)
+    o = o / jnp.where(l_all > 0.0, l_all, 1.0)[..., None]
+    return o.astype(q.dtype)

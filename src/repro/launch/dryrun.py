@@ -1,43 +1,47 @@
+"""Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
+
+Inputs are ShapeDtypeStructs (no allocation); the run prints memory/cost
+analysis and records roofline inputs (FLOPs, bytes, collective traffic) as
+JSON under artifacts/dryrun/. Run as a program, it appends
+``--xla_force_host_platform_device_count=512`` to ``XLA_FLAGS`` so the CPU
+backend can stand in for the production meshes; importing the module
+changes nothing. Usage:
+
+    PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-135m --shape train_4k
+    PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
+"""
+
+import argparse
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import time
+import traceback
+from pathlib import Path
 
-# Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell with
-# ShapeDtypeStruct inputs (no allocation), print memory/cost analysis, and
-# record roofline inputs (FLOPs, bytes, collective traffic) as JSON under
-# artifacts/dryrun/.  Usage:
-#   PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-135m --shape train_4k
-#   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import argparse  # noqa: E402
-import json  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from pathlib import Path  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from ..configs import ARCHS, get_arch  # noqa: E402
-from ..configs.shapes import (  # noqa: E402
+from ..configs import ARCHS, get_arch
+from ..configs.shapes import (
     SHAPES,
     cache_specs,
     decode_token_specs,
     supports_long_context,
     token_batch_specs,
 )
-from ..models.api import family_of  # noqa: E402
-from ..parallel.sharding import (  # noqa: E402
+from ..models.api import family_of
+from ..parallel.sharding import (
     batch_shardings,
     make_rules,
     make_sharder,
     tree_shardings,
 )
-from ..train import optimizer as opt  # noqa: E402
-from ..train.step import TrainState, init_state, make_serve_steps, make_train_step, state_axes  # noqa: E402
-from ..utils import hlo as hlo_utils  # noqa: E402
-from ..utils.roofline import RooflineReport, model_flops  # noqa: E402
-from .mesh import make_production_mesh  # noqa: E402
+from ..train import optimizer as opt
+from ..train.step import TrainState, init_state, make_serve_steps, make_train_step, state_axes
+from ..utils import hlo as hlo_utils
+from ..utils.roofline import RooflineReport, model_flops
+from .mesh import make_production_mesh
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
 
@@ -270,4 +274,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512"
+    ]))
     main()

@@ -3,8 +3,8 @@
     PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
         --steps 200 --batch 8 --seq 256 --smoke
 
-Runs the full production stack on whatever devices exist (CPU here, pod on
-real hardware): sharded train step, deterministic data pipeline, async
+Runs the full production stack on the local devices (all of them, or the
+first ``--devices``): sharded train step, deterministic data pipeline, async
 checkpointing, fault-tolerant supervisor, optional offload arena. ``--smoke``
 selects the reduced config so a ~100M-class model trains for a few hundred
 steps on one host.
@@ -29,6 +29,7 @@ from ..models.api import family_of
 from ..parallel.sharding import make_rules, make_sharder, tree_shardings
 from ..train import optimizer as opt
 from ..train.step import TrainState, init_state, make_train_step, state_axes
+from ..utils.device import enable_compile_cache
 from .mesh import make_host_mesh
 
 log = logging.getLogger("repro.train")
@@ -46,6 +47,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--ckpt-dir", default="artifacts/ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--model-parallel", type=int, default=1)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="use the first N local devices (default: all)")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
@@ -54,7 +57,7 @@ def main(argv=None) -> dict:
     cfg = entry.smoke if args.smoke else entry.full
     fam = family_of(cfg)
 
-    mesh = make_host_mesh(model=args.model_parallel)
+    mesh = make_host_mesh(model=args.model_parallel, n_devices=args.devices)
     rules = make_rules(mesh, kind="train", seq_parallel=False)
     sharder = make_sharder(mesh, rules)
     adamw = opt.AdamWConfig(lr=args.lr)
@@ -92,7 +95,9 @@ def main(argv=None) -> dict:
     losses = [h["loss"] for h in history]
     result = {
         "arch": cfg.name,
+        "devices": int(mesh.devices.size),
         "steps": len(history),
+        "losses": losses,
         "first_loss": losses[0],
         "last_loss": losses[-1],
         "min_loss": min(losses),
@@ -102,10 +107,12 @@ def main(argv=None) -> dict:
     }
     for h in history[:: max(1, args.log_every)]:
         log.info("step %5d loss %.4f", h["step"], h["loss"])
-    print(json.dumps({k: v for k, v in result.items() if k != "events"}, indent=2))
+    print(json.dumps({k: v for k, v in result.items()
+                      if k not in ("events", "losses")}, indent=2))
     return result
 
 
 if __name__ == "__main__":
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     main()

@@ -28,7 +28,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import layers as L

@@ -21,7 +21,7 @@ from typing import Any, Callable, List
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 
 def pipeline_forward(

@@ -58,7 +58,8 @@ class EngineConfig:
     max_len: int = 1024
     n_chunks: int = 512
     interpret: bool = False
-    use_reference_ops: bool = True  # CPU-friendly default
+    #: run the KV cache's device paths on the jnp references, not the kernels
+    use_reference_ops: bool = False
     #: KV-arena backend: any ``repro.alloc`` registry key (or instance)
     allocator: object = "gmlake"
     #: optional KV *accounting* geometry overrides (n_kv heads / head dim).
@@ -93,6 +94,7 @@ class ServeEngine:
                 head_dim=engine_cfg.kv_head_dim or getattr(cfg, "dh", 64),
                 dtype=jnp.bfloat16,
                 n_chunks=engine_cfg.n_chunks,
+                interpret=engine_cfg.interpret,
                 use_reference_ops=engine_cfg.use_reference_ops,
             ),
             recorder=self.recorder,

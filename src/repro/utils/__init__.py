@@ -1,1 +1,1 @@
-"""Analysis utilities: scan-aware HLO walker, roofline model."""
+"""Analysis utilities: scan-aware HLO walker, roofline model, device setup."""

@@ -1,9 +1,7 @@
 """Three-term roofline model from compiled dry-run artifacts.
 
-Target hardware: TPU v5e —
-  peak compute  197 TFLOP/s bf16 per chip
-  HBM bandwidth 819 GB/s per chip
-  ICI           ~50 GB/s per link per chip
+Peaks live in one table keyed by ``jax.Device.device_kind``; a device that
+is not in the table is an error, never a default.
 
 Terms (assignment formulas; all reduce to per-chip quantities because the
 compiled module is the per-device program):
@@ -17,9 +15,31 @@ from __future__ import annotations
 from dataclasses import asdict, dataclass, field
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link / chip
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # ICI bytes/s per link per chip
+    hbm_bytes: int  # HBM capacity per chip
+
+
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of
+#: HBM at 819 GB/s, 1,600 Gbit/s of interconnect per chip over 4 links.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                             hbm_bytes=16 * 10**9),
+}
+
+#: the chip the production meshes and the dry run describe (v5e pods)
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 @dataclass
@@ -33,21 +53,26 @@ class RooflineReport:
     collective_bytes_per_device: float
     model_flops: float  # 6*N*D (dense) or 6*N_active*D (MoE), global
     n_devices: int
+    device_kind: str = DRYRUN_DEVICE_KIND
     peak_memory_per_device: Optional[float] = None
     collectives: Dict[str, Dict[str, float]] = field(default_factory=dict)
     notes: str = ""
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return peaks_for(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -73,7 +98,7 @@ class RooflineReport:
     def roofline_fraction(self) -> float:
         """How close the step would run to the compute roofline if it achieved
         the no-overlap lower bound: useful-compute-time / bound."""
-        t_useful = (self.model_flops / self.n_devices) / PEAK_FLOPS
+        t_useful = (self.model_flops / self.n_devices) / self.peaks.flops
         lb = self.step_time_lower_bound
         return t_useful / lb if lb else 0.0
 

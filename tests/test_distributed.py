@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_auto_mesh
 from repro.parallel.sharding import (
     BASE_RULES,
     make_rules,
@@ -45,13 +46,13 @@ def run_with_devices(code: str, n: int = 8) -> str:
 
 
 def _mesh22():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_auto_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_divisibility_fallback():
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_auto_mesh((1,), ("model",))
     rules = {"heads": "model", "ffn": "model"}
     # heads=9 not divisible by axis 1? axis size 1 divides everything;
     # simulate axis>dim with a fake rule check via zero_extend instead:
@@ -60,17 +61,17 @@ def test_spec_divisibility_fallback():
 
 
 def test_make_rules_filters_missing_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, kind="train")
     assert rules["batch"] == ("data",)  # 'pod' filtered out
     rules_mp = make_rules(
-        jax.make_mesh((1, 1, 1), ("pod", "data", "model")), kind="train"
+        make_auto_mesh((1, 1, 1), ("pod", "data", "model")), kind="train"
     )
     assert rules_mp["batch"] == ("pod", "data")
 
 
 def test_decode_rules_long_context():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     r = make_rules(mesh, kind="decode", long_context=True)
     assert r["kv_seq"] == ("data", "model")
     r2 = make_rules(mesh, kind="decode", long_context=False)
@@ -80,13 +81,14 @@ def test_decode_rules_long_context():
 def test_zero_extend_picks_largest_free_dim():
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((2, 4), ("data", "model")) if False else None
+    mesh = make_auto_mesh((2, 4), ("data", "model")) if False else None
     # run in subprocess (needs 8 devices)
     out = run_with_devices("""
         import jax
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_auto_mesh
         from repro.parallel.sharding import zero_extend
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
         spec = zero_extend(P(None, "model"), (64, 128), mesh, ("data",))
         assert spec == P("data", "model"), spec
         # already data-sharded -> unchanged
@@ -109,9 +111,10 @@ def test_compressed_psum_error_feedback():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.utils.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_auto_mesh
         from repro.parallel.collectives import compressed_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_auto_mesh((8,), ("data",))
 
         def sync(g, r):
             return compressed_psum(g, r, "data")
@@ -140,9 +143,10 @@ def test_overlapped_all_gather_matches_dense():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.utils.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_auto_mesh
         from repro.parallel.collectives import overlapped_all_gather, ring_layer_matmul
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_auto_mesh((8,), ("data",))
         w = jax.random.normal(jax.random.PRNGKey(0), (64, 32))
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 64))
 
@@ -161,8 +165,9 @@ def test_overlapped_all_gather_matches_dense():
 def test_pipeline_parallel_matches_sequential():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_auto_mesh
         from repro.parallel.pipeline import pipeline_forward, split_stages
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_auto_mesh((4,), ("pod",))
         L, d = 8, 16
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (L, d, d)) * 0.3

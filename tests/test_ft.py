@@ -54,12 +54,14 @@ def test_checkpoint_async_and_retention(tmp_path):
 
 def test_checkpoint_elastic_restore_new_sharding(tmp_path):
     """Restore re-shards to the current mesh (sharding != save-time)."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_auto_mesh
 
     mgr = CheckpointManager(tmp_path)
     state = {"w": jnp.arange(16.0).reshape(4, 4)}
     mgr.save(1, state)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_auto_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data"))}
     back = mgr.restore(state, shardings=sh)
     assert back["w"].sharding == sh["w"]

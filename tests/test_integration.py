@@ -37,9 +37,10 @@ def run_with_devices(code: str, n: int = 8) -> str:
 def test_moe_a2a_matches_global_dispatch():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_auto_mesh
         from repro.models import moe as M
         from repro.parallel.sharding import make_rules, make_sharder
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
         mk = lambda a2a, gated: M.MoEConfig(
             name="t", n_layers=2, d_model=64, n_heads=4, n_kv=2, d_ff=96,
             vocab=211, n_experts=4, top_k=2, capacity_factor=8.0,
@@ -250,3 +251,31 @@ def test_end_to_end_training_loss_decreases(tmp_path):
     ])
     assert result["steps"] == 40
     assert result["last_loss"] < result["first_loss"]
+
+
+# ---------------------------------------------------------------------------
+# launcher device setup
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The compile cache goes where JAX_COMPILATION_CACHE_DIR says (and the
+    helper sets nothing), else to the fixed .jax_cache in the checkout."""
+    from repro.utils import device
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = device.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == os.path.join(REPO, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
