@@ -954,6 +954,9 @@ class GMLakeAllocator:
         self.plan_identity = plan_identity
         self.stats = AllocatorStats(record_timeline=record_timeline)
         self.state_counts: Dict[str, int] = {f"S{i}": 0 for i in range(1, 6)}
+        #: mallocs that handed out stitched memory (an sBlock: S1 re-use of
+        #: one, S3, or S4 with inactive members); not digest material
+        self.stitched_mallocs = 0
         #: round-4 fast-path hit counters (diagnostics only; not digest
         #: material). Shared into ``stats.counters`` for the profile harness.
         self.hotspots: Dict[str, int] = {
@@ -2152,6 +2155,7 @@ class GMLakeAllocator:
                 ) from e
         if isinstance(block, SBlock):
             block.last_use = self._tick
+            self.stitched_mallocs += 1
         self.stats.on_alloc(block.size, self.reserved_bytes)
         return Allocation(req_size=size, block_size=block.size, block=block, owner=self)
 

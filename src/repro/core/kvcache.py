@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..alloc.caching_allocator import Allocation
 from ..alloc.chunks import CHUNK_SIZE
 from ..kernels import ops
@@ -126,7 +127,7 @@ class StitchedKVCache:
         assert seq_id not in self.seqs
         state = _SeqState()
         self.seqs[seq_id] = state
-        self._grow_to(state, n_tokens)
+        self._grow_to(seq_id, n_tokens)
         state.length = n_tokens
 
     def append_tokens(self, seq_id: int, n: int = 1) -> None:
@@ -136,28 +137,31 @@ class StitchedKVCache:
                 state.length + n,
                 int(state.capacity_tokens * (1.0 + self.config.growth)),
             )
-            self._grow_to(state, want)
+            self._grow_to(seq_id, want)
         state.length += n
 
     def free_sequence(self, seq_id: int) -> None:
-        state = self.seqs.pop(seq_id)
-        for allocs in state.allocs.values():
-            for a in allocs:
-                self.arena.free(a)
+        with obs.span("kv.free", req=seq_id) if obs.ON else obs.OFF:
+            state = self.seqs.pop(seq_id)
+            for allocs in state.allocs.values():
+                for a in allocs:
+                    self.arena.free(a)
 
-    def _grow_to(self, state: _SeqState, n_tokens: int) -> None:
+    def _grow_to(self, seq_id: int, n_tokens: int) -> None:
         c = self.config
+        state = self.seqs[seq_id]
         need_chunks = -(-n_tokens // c.chunk_tokens)
         have_chunks = state.capacity_tokens // c.chunk_tokens
         if need_chunks <= have_chunks:
             return
         delta = (need_chunks - have_chunks) * self.arena.config.chunk_elems
-        for layer in range(c.n_layers):
-            for kv in ("k", "v"):
-                key = (layer, kv)
-                state.allocs.setdefault(key, []).append(
-                    self.arena.alloc_elems(delta, f"kv.{kv}.L{layer}")
-                )
+        with obs.span("kv.grow", req=seq_id) if obs.ON else obs.OFF:
+            for layer in range(c.n_layers):
+                for kv in ("k", "v"):
+                    key = (layer, kv)
+                    state.allocs.setdefault(key, []).append(
+                        self.arena.alloc_elems(delta, f"kv.{kv}.L{layer}")
+                    )
         state.capacity_tokens = need_chunks * c.chunk_tokens
 
     # ------------------------------------------------------------------

@@ -2,9 +2,11 @@
 
 The serving-side integration of GMLake (DESIGN.md §2.3): each request's KV
 history is a stitched allocation; admission/retirement churn is exactly the
-irregular alloc/free stream that fragments a splitting allocator, and the
-engine emits the real trace through ``TraceRecorder`` so the benchmark can
-replay it against caching vs GMLake.
+irregular alloc/free stream that fragments a splitting allocator. The
+engine records that allocation event stream through ``TraceRecorder``, so
+that it can be replayed against caching vs GMLake. Its timing spans and
+counters (``serve.step`` and the phases inside it, ``serve.host_syncs``)
+are ``repro.obs``'s, off unless a caller enables them.
 
 The engine is deliberately modest about model execution — it drives any
 registered family's prefill/decode on real (small) shapes; its value here
@@ -21,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.kvcache import KVCacheConfig, StitchedKVCache
 from ..core.trace import TraceRecorder
 from ..models.api import family_of
@@ -134,23 +137,31 @@ class ServeEngine:
             )
         while self.waiting and len(self.running) < self.ecfg.max_batch:
             req = self.waiting.pop(0)
-            self.running[req.req_id] = req
-            self.recorder.set_context(req.tenant, req.slo)
-            self.kv.add_sequence(req.req_id, len(req.prompt))
-            self.recorder.set_context()
-            slot = self._alloc_slot(req)
-            # dense prefill for this request alone (simple; batched prefill
-            # is an optimization the engine does not need for correctness)
-            cache = self.fam.init_cache(self.cfg, 1, self.ecfg.max_len)
-            logits, cache = self.fam.prefill(
-                self.cfg, self.params,
-                {"tokens": jnp.asarray(req.prompt[None, :])}, cache,
-            )
-            tok = int(jnp.argmax(logits[0, -1]))
-            req.generated.append(tok)
-            if req.first_token_step is None:
-                req.first_token_step = self.steps
-            self._merge_cache(slot, cache)
+            rid = req.req_id
+            with obs.span("serve.admit", req=rid) if obs.ON else obs.OFF:
+                self.running[rid] = req
+                self.recorder.set_context(req.tenant, req.slo)
+                self.kv.add_sequence(rid, len(req.prompt))
+                self.recorder.set_context()
+                slot = self._alloc_slot(req)
+                # dense prefill for this request alone (simple; batched
+                # prefill is an optimization the engine does not need for
+                # correctness)
+                cache = self.fam.init_cache(self.cfg, 1, self.ecfg.max_len)
+                with obs.span("serve.prefill", req=rid) if obs.ON else obs.OFF:
+                    logits, cache = self.fam.prefill(
+                        self.cfg, self.params,
+                        {"tokens": jnp.asarray(req.prompt[None, :])}, cache,
+                    )
+                with obs.span("serve.sample", req=rid) if obs.ON else obs.OFF:
+                    tok = int(jnp.argmax(logits[0, -1]))
+                if obs.ON:
+                    obs.count("serve.host_syncs")
+                req.generated.append(tok)
+                if req.first_token_step is None:
+                    req.first_token_step = self.steps
+                with obs.span("serve.merge", req=rid) if obs.ON else obs.OFF:
+                    self._merge_cache(slot, cache)
 
     def _alloc_slot(self, req: Request) -> int:
         slot = len(self._slot_of)
@@ -185,38 +196,44 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def step(self) -> int:
         """One decode step over the running batch. Returns #finished."""
-        self._dirty = True
-        self._admit()
-        if not self.running:
+        with obs.span("serve.step", step=self.steps) if obs.ON else obs.OFF:
+            self._dirty = True
+            self._admit()
+            if not self.running:
+                self.steps += 1
+                self._dirty = False
+                return 0
+            reqs = list(self.running.values())
+            slots = [self._slot_of[r.req_id] for r in reqs]
+            tokens = np.zeros((self.ecfg.max_batch,), np.int32)
+            for r, s in zip(reqs, slots):
+                tokens[s] = r.generated[-1]
+            with obs.span("serve.decode") if obs.ON else obs.OFF:
+                logits, self._cache = self.fam.decode_step(
+                    self.cfg, self.params, self._cache, jnp.asarray(tokens)
+                )
+            # one blocking device-to-host read per running sequence
+            with obs.span("serve.sample") if obs.ON else obs.OFF:
+                toks = [int(jnp.argmax(logits[s])) for s in slots]
+            if obs.ON:
+                obs.count("serve.host_syncs", len(toks))
+            finished = 0
+            for r, tok in zip(reqs, toks):
+                r.generated.append(tok)
+                self.recorder.set_context(r.tenant, r.slo)
+                self.kv.append_tokens(r.req_id, 1)
+                self.recorder.set_context()
+                if len(r.generated) >= r.max_new:
+                    r.done = True
+                    r.finish_step = self.steps
+                    finished += 1
+                    self.finished.append(r)
+                    self.kv.free_sequence(r.req_id)
+                    del self.running[r.req_id]
+                    del self._slot_of[r.req_id]
             self.steps += 1
             self._dirty = False
-            return 0
-        reqs = list(self.running.values())
-        slots = [self._slot_of[r.req_id] for r in reqs]
-        tokens = np.zeros((self.ecfg.max_batch,), np.int32)
-        for r, s in zip(reqs, slots):
-            tokens[s] = r.generated[-1]
-        logits, self._cache = self.fam.decode_step(
-            self.cfg, self.params, self._cache, jnp.asarray(tokens)
-        )
-        finished = 0
-        for r, s in zip(reqs, slots):
-            tok = int(jnp.argmax(logits[s]))
-            r.generated.append(tok)
-            self.recorder.set_context(r.tenant, r.slo)
-            self.kv.append_tokens(r.req_id, 1)
-            self.recorder.set_context()
-            if len(r.generated) >= r.max_new:
-                r.done = True
-                r.finish_step = self.steps
-                finished += 1
-                self.finished.append(r)
-                self.kv.free_sequence(r.req_id)
-                del self.running[r.req_id]
-                del self._slot_of[r.req_id]
-        self.steps += 1
-        self._dirty = False
-        return finished
+            return finished
 
     def run_to_completion(self, max_steps: int = 10_000) -> List[Request]:
         """Drive ``step`` until every submitted request finishes (or the
