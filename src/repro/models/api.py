@@ -2,12 +2,24 @@
 
 The launcher, trainer, server and dry-run all go through ``family_of(cfg)``
 so adding an architecture is: write the module, register the family.
+
+The registry's serving entry points, ``prefill`` and ``decode_step``, are
+jitted once here, with the config (a frozen, hashable dataclass) and the
+``sharder`` static. A caller that runs them eagerly, as ``ServeEngine``
+does once per step, then gets one program per input shape instead of a
+fresh lowering of the layer scan on every call. The module functions
+(``transformer.prefill`` and the rest) stay plain: the consistency tests
+compare against them, and ``train.step.make_serve_steps`` and
+``launch/dryrun.py`` trace the registry's entry points inside their own
+``jit``, where the inner one is inlined.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional
+from dataclasses import dataclass, replace
+from typing import Callable, Dict
+
+import jax
 
 from . import moe, paligemma, rwkv6, transformer, whisper, zamba2
 
@@ -24,7 +36,16 @@ class Family:
     cache_axes: Callable
 
 
-FAMILIES: Dict[str, Family] = {
+def _jit_serving(fam: Family) -> Family:
+    """``fam`` with ``prefill`` and ``decode_step`` jitted, config static."""
+    def serving(fn):
+        return jax.jit(fn, static_argnums=0, static_argnames="sharder")
+
+    return replace(fam, prefill=serving(fam.prefill),
+                   decode_step=serving(fam.decode_step))
+
+
+_PLAIN: Dict[str, Family] = {
     "dense": Family(
         "dense", transformer.init_params, transformer.param_axes, transformer.loss_fn,
         transformer.prefill, transformer.decode_step, transformer.init_cache,
@@ -52,6 +73,8 @@ FAMILIES: Dict[str, Family] = {
         paligemma.cache_axes,
     ),
 }
+
+FAMILIES: Dict[str, Family] = {k: _jit_serving(f) for k, f in _PLAIN.items()}
 
 
 def family_of(cfg) -> Family:
