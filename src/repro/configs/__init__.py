@@ -13,6 +13,7 @@ from typing import Any, Dict
 
 from . import (
     dbrx_132b,
+    deepseek_v2_lite,
     grok1_314b,
     h2o_danube3_4b,
     internlm2_20b,
@@ -69,6 +70,8 @@ ARCHS: Dict[str, ArchEntry] = {
                   opt_dtype="bfloat16"),
         ArchEntry("whisper-medium", whisper_medium.FULL, whisper_medium.SMOKE,
                   zero=False),
+        ArchEntry("deepseek-v2-lite", deepseek_v2_lite.FULL, deepseek_v2_lite.SMOKE,
+                  zero=True),
     ]
 }
 

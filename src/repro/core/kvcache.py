@@ -7,14 +7,15 @@ allocations that grow geometrically), so the page table stays short and the
 attention kernel walks long physically-contiguous extents — fewer, larger
 DMAs on TPU.
 
-Token layout: one 2 MB chunk holds ``chunk_tokens`` tokens of K (or V) for
-ONE layer, packed in order into a lane-dense ``(chunk_rows, row_lanes)``
-slab: ``tokens_per_row`` token rows of ``n_kv * head_dim`` elements share a
-lane row, so ``row_lanes`` is a multiple of 128 and any head geometry tiles
+Token layout: one 2 MB chunk holds ``chunk_tokens`` tokens of one stream
+(K, V or a latent row) for ONE layer, packed in order into a lane-dense
+``(chunk_rows, row_lanes)`` slab: ``tokens_per_row`` token rows of
+``n_kv * head_dim`` elements share a lane row, so ``row_lanes`` is a
+multiple of 128 and any head geometry tiles
 without padding (smollm-135m's 3x64 and h2o-danube-3-4b's 8x120 rows do not
-divide 2 MB). The few bytes a chunk cannot fit stay unused. K and V of every
-layer share the single arena (one memory lake), each with its own
-allocation per sequence.
+divide 2 MB). The few bytes a chunk cannot fit stay unused. Every stream of
+every layer (K and V, or MLA's one latent row) shares the single arena (one
+memory lake), each with its own allocation per sequence.
 """
 
 from __future__ import annotations
@@ -42,6 +43,8 @@ class KVCacheConfig:
     n_layers: int
     n_kv: int
     head_dim: int
+    #: allocations per layer per sequence: K and V, or one latent row
+    streams: Tuple[str, ...] = ("k", "v")
     dtype: jnp.dtype = jnp.bfloat16
     n_chunks: int = 1024
     #: new allocations grow by at least this fraction of current capacity
@@ -90,7 +93,7 @@ class KVCacheConfig:
 class _SeqState:
     length: int = 0
     capacity_tokens: int = 0
-    # one allocation list per (layer, k|v); growth appends allocations and
+    # one allocation list per (layer, stream); growth appends allocations and
     # their extents concatenate into the logical block — the stitch.
     allocs: Dict[Tuple[int, str], List[Allocation]] = field(default_factory=dict)
 
@@ -157,10 +160,9 @@ class StitchedKVCache:
         delta = (need_chunks - have_chunks) * self.arena.config.chunk_elems
         with obs.span("kv.grow", req=seq_id) if obs.ON else obs.OFF:
             for layer in range(c.n_layers):
-                for kv in ("k", "v"):
-                    key = (layer, kv)
-                    state.allocs.setdefault(key, []).append(
-                        self.arena.alloc_elems(delta, f"kv.{kv}.L{layer}")
+                for stream in c.streams:
+                    state.allocs.setdefault((layer, stream), []).append(
+                        self.arena.alloc_elems(delta, f"kv.{stream}.L{layer}")
                     )
         state.capacity_tokens = need_chunks * c.chunk_tokens
 

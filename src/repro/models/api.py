@@ -12,16 +12,42 @@ fresh lowering of the layer scan on every call. The module functions
 compare against them, and ``train.step.make_serve_steps`` and
 ``launch/dryrun.py`` trace the registry's entry points inside their own
 ``jit``, where the inner one is inlined.
+
+Each family also declares its KV layout (``kv_layout``): how many
+allocation streams a layer keeps per sequence and how many elements one
+token's row of each holds. ``ServeEngine`` sizes the stitched KV arena
+from it.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Callable, Dict
+from typing import Callable, Dict, Tuple
 
 import jax
 
-from . import moe, paligemma, rwkv6, transformer, whisper, zamba2
+from . import deepseek_v2, moe, paligemma, rwkv6, transformer, whisper, zamba2
+
+
+@dataclass(frozen=True)
+class KVLayout:
+    """Per layer, one allocation per stream per sequence, each holding
+    ``n_kv * head_dim`` elements a token."""
+    n_layers: int
+    n_kv: int
+    head_dim: int
+    streams: Tuple[str, ...] = ("k", "v")
+
+
+def heads_kv_layout(cfg) -> KVLayout:
+    """K and V of ``n_kv x dh`` per layer."""
+    return KVLayout(getattr(cfg, "n_layers", 1), getattr(cfg, "n_kv", 1),
+                    getattr(cfg, "dh", 64))
+
+
+def latent_kv_layout(cfg: deepseek_v2.DeepseekV2Config) -> KVLayout:
+    """MLA: one row of latent plus rotary key per layer."""
+    return KVLayout(cfg.n_layers, 1, cfg.latent_dim, ("latent",))
 
 
 @dataclass(frozen=True)
@@ -34,6 +60,7 @@ class Family:
     decode_step: Callable
     init_cache: Callable
     cache_axes: Callable
+    kv_layout: Callable = heads_kv_layout
 
 
 def _jit_serving(fam: Family) -> Family:
@@ -72,6 +99,11 @@ _PLAIN: Dict[str, Family] = {
         paligemma.prefill, paligemma.decode_step, paligemma.init_cache,
         paligemma.cache_axes,
     ),
+    "mla_moe": Family(
+        "mla_moe", deepseek_v2.init_params, deepseek_v2.param_axes, deepseek_v2.loss_fn,
+        deepseek_v2.prefill, deepseek_v2.decode_step, deepseek_v2.init_cache,
+        deepseek_v2.cache_axes, latent_kv_layout,
+    ),
 }
 
 FAMILIES: Dict[str, Family] = {k: _jit_serving(f) for k, f in _PLAIN.items()}
@@ -90,4 +122,6 @@ def family_of(cfg) -> Family:
         return FAMILIES["ssm"]
     if isinstance(cfg, whisper.WhisperConfig):
         return FAMILIES["audio"]
+    if isinstance(cfg, deepseek_v2.DeepseekV2Config):
+        return FAMILIES["mla_moe"]
     raise TypeError(f"unknown model config type {type(cfg)}")

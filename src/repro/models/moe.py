@@ -132,6 +132,15 @@ def param_axes(cfg: MoEConfig) -> Dict:
 # ---------------------------------------------------------------------------
 
 
+def _expert_slots(flat_e: jax.Array):
+    """Assignments sorted by expert: ``(order, sorted_e, rank)``, where
+    ``rank`` is each sorted assignment's place among its expert's."""
+    order = jnp.argsort(flat_e, stable=True)
+    sorted_e = flat_e[order]
+    rank = jnp.arange(flat_e.shape[0]) - jnp.searchsorted(sorted_e, sorted_e, side="left")
+    return order, sorted_e, rank
+
+
 def moe_apply(cfg: MoEConfig, p: Dict, x: jax.Array, sharder: Sharder):
     """x (B, S, d) -> (out (B, S, d), aux_loss scalar)."""
     mesh = getattr(sharder, "mesh", None)
@@ -163,10 +172,7 @@ def moe_apply(cfg: MoEConfig, p: Dict, x: jax.Array, sharder: Sharder):
     # capacity floor: tiny (decode-sized) batches must never drop tokens —
     # a hot expert can legitimately receive every token of a small batch
     capacity = max(int(cfg.capacity_factor * n_tok * k / e), min(n_tok, 16))
-    flat_e = topi.reshape(-1)  # (T*k,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    rank = jnp.arange(n_tok * k) - jnp.searchsorted(sorted_e, sorted_e, side="left")
+    order, sorted_e, rank = _expert_slots(topi.reshape(-1))
     tok = order // k  # source token per sorted slot
 
     # dispatch: (E, C, d); slots past capacity are dropped
@@ -201,6 +207,76 @@ def moe_apply(cfg: MoEConfig, p: Dict, x: jax.Array, sharder: Sharder):
     prob_frac = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(dispatch_frac / k * prob_frac)
     return out.reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# MoE FFN with shared experts and a held share of the routed ones
+# (DeepSeek-V2): dropless, no capacity
+# ---------------------------------------------------------------------------
+
+
+def route(x: jax.Array, router: jax.Array, top_k: int, norm_topk_prob: bool,
+          scaling: float = 1.0):
+    """Greedy softmax top-k over every routed expert, in float32.
+
+    x (T, d), router (d, E) -> (weights (T, k) float32, experts (T, k)).
+    The weights are the softmax probabilities themselves unless
+    ``norm_topk_prob`` renormalises them over the k chosen, then times
+    ``scaling``."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router.astype(jnp.float32))
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights * scaling, experts
+
+
+def held_experts(p: Dict, x: jax.Array, weights: jax.Array, experts: jax.Array,
+                 offset: int, act: str = "silu") -> jax.Array:
+    """The routed part of the result that the experts held here give.
+
+    ``p`` holds experts ``[offset, offset + H)`` of the router's, as gated
+    FFNs ``wi``/``wg`` (H, d, f) and ``wo`` (H, f, d). Each assignment
+    (token, expert) that lands on a held expert is computed and weighted by
+    its routing weight; the others are left out. Dropless: top-k picks k
+    distinct experts, so no expert is given one token twice, and a slot per
+    token (H, T, d) holds every assignment. x (T, d) -> (T, d)."""
+    n_tok, d = x.shape
+    held = p["wi"].shape[0]
+    k = experts.shape[1]
+    local = experts.reshape(-1) - offset
+    local = jnp.where((local >= 0) & (local < held), local, held)  # held: not here
+    order, sorted_e, rank = _expert_slots(local)
+    # source token of each (expert, slot); n_tok (a zero row) where empty
+    src = jnp.full((held, n_tok), n_tok, jnp.int32).at[sorted_e, rank].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    xpad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])
+    buf = xpad[src]  # (H, T, d)
+    h = jnp.einsum("etd,edf->etf", buf, p["wi"])
+    h = L.ACTIVATIONS[act](jnp.einsum("etd,edf->etf", buf, p["wg"])) * h
+    y = jnp.einsum("etf,efd->etd", h, p["wo"])
+    # each assignment's (expert, slot), back in token order; off-share ones
+    # point past the held experts and read 0
+    slot_e = jnp.zeros_like(local).at[order].set(sorted_e)
+    slot_r = jnp.zeros_like(local).at[order].set(rank)
+    picked = y.at[slot_e, slot_r].get(mode="fill", fill_value=0).reshape(n_tok, k, d)
+    out = jnp.einsum("tkd,tk->td", picked, weights, preferred_element_type=jnp.float32)
+    return out.astype(x.dtype)
+
+
+def shared_moe(p: Dict, x: jax.Array, *, top_k: int, offset: int,
+               norm_topk_prob: bool, scaling: float, act: str = "silu") -> jax.Array:
+    """Routed experts (the held share) plus the shared experts, which every
+    token passes through in full. ``p``: ``router`` (d, E), ``experts``
+    (see ``held_experts``), ``shared`` (a gated MLP whose width is the
+    shared experts' together). x (..., d) -> (..., d)."""
+    xf = x.reshape(-1, x.shape[-1])
+    with jax.named_scope("moe.route"):
+        weights, experts = route(xf, p["router"], top_k, norm_topk_prob, scaling)
+    with jax.named_scope("moe.experts"):
+        y = held_experts(p["experts"], xf, weights, experts, offset, act)
+    with jax.named_scope("moe.shared"):
+        y = y + L.mlp_apply(p["shared"], xf, act, gated=True)
+    return y.reshape(x.shape)
 
 
 # ---------------------------------------------------------------------------

@@ -90,11 +90,13 @@ class ServeEngine:
         self.ecfg = engine_cfg
         self.fam = family_of(cfg)
         self.recorder = TraceRecorder(kind="serve", model=cfg.name)
+        layout = self.fam.kv_layout(cfg)
         self.kv = StitchedKVCache(
             KVCacheConfig(
-                n_layers=getattr(cfg, "n_layers", 1),
-                n_kv=engine_cfg.kv_n_kv or getattr(cfg, "n_kv", 1),
-                head_dim=engine_cfg.kv_head_dim or getattr(cfg, "dh", 64),
+                n_layers=layout.n_layers,
+                n_kv=engine_cfg.kv_n_kv or layout.n_kv,
+                head_dim=engine_cfg.kv_head_dim or layout.head_dim,
+                streams=layout.streams,
                 dtype=jnp.bfloat16,
                 n_chunks=engine_cfg.n_chunks,
                 interpret=engine_cfg.interpret,
@@ -148,7 +150,8 @@ class ServeEngine:
                 # prefill is an optimization the engine does not need for
                 # correctness)
                 cache = self.fam.init_cache(self.cfg, 1, self.ecfg.max_len)
-                with obs.span("serve.prefill", req=rid) if obs.ON else obs.OFF:
+                with (obs.span("serve.prefill", req=rid, tokens=len(req.prompt))
+                      if obs.ON else obs.OFF):
                     logits, cache = self.fam.prefill(
                         self.cfg, self.params,
                         {"tokens": jnp.asarray(req.prompt[None, :])}, cache,

@@ -146,8 +146,9 @@ def test_engine_phases_in_order_and_host_syncs(tracer, tiny_model):
         frees += kids.count("kv.free")
         for j in admits:
             rid = spans[j][4]["req"]
+            tokens = len(eng._requests[rid].prompt)
             assert [(s[0], s[4]) for _, s in children(j)] == [
-                ("kv.grow", {"req": rid}), ("serve.prefill", {"req": rid}),
+                ("kv.grow", {"req": rid}), ("serve.prefill", {"req": rid, "tokens": tokens}),
                 ("serve.sample", {"req": rid}), ("serve.merge", {"req": rid})]
     assert frees == 3
     # each served token is one sync: the first at admission, one per decode
