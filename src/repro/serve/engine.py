@@ -38,6 +38,18 @@ SLO_PRIORITY = {"interactive": 0, "standard": 1, "batch": 2}
 _DEFAULT_PRIORITY = 1
 
 
+@jax.jit
+def _greedy(logits: jax.Array) -> jax.Array:
+    """Every batch row's greedy token: ``(B, V)`` decode logits -> ``(B,)``."""
+    return jnp.argmax(logits, axis=-1)
+
+
+@jax.jit
+def _first_token(logits: jax.Array) -> jax.Array:
+    """The greedy token after a prompt: ``(1, S, V)`` prefill logits -> ``()``."""
+    return jnp.argmax(logits[0, -1])
+
+
 @dataclass
 class Request:
     req_id: int
@@ -137,6 +149,9 @@ class ServeEngine:
             self.waiting.sort(
                 key=lambda r: SLO_PRIORITY.get(r.slo, _DEFAULT_PRIORITY)
             )
+        # each admitted request's first token stays on the device until
+        # the batch is admitted; then one blocking read brings them all
+        firsts = []
         while self.waiting and len(self.running) < self.ecfg.max_batch:
             req = self.waiting.pop(0)
             rid = req.req_id
@@ -156,15 +171,19 @@ class ServeEngine:
                         self.cfg, self.params,
                         {"tokens": jnp.asarray(req.prompt[None, :])}, cache,
                     )
-                with obs.span("serve.sample", req=rid) if obs.ON else obs.OFF:
-                    tok = int(jnp.argmax(logits[0, -1]))
-                if obs.ON:
-                    obs.count("serve.host_syncs")
-                req.generated.append(tok)
-                if req.first_token_step is None:
-                    req.first_token_step = self.steps
+                firsts.append((req, _first_token(logits)))
                 with obs.span("serve.merge", req=rid) if obs.ON else obs.OFF:
                     self._merge_cache(slot, cache)
+        if not firsts:
+            return
+        with obs.span("serve.sample") if obs.ON else obs.OFF:
+            toks = jax.device_get([tok for _, tok in firsts])
+        if obs.ON:
+            obs.count("serve.host_syncs")
+        for (req, _), tok in zip(firsts, toks):
+            req.generated.append(int(tok))
+            if req.first_token_step is None:
+                req.first_token_step = self.steps
 
     def _alloc_slot(self, req: Request) -> int:
         slot = len(self._slot_of)
@@ -215,14 +234,14 @@ class ServeEngine:
                 logits, self._cache = self.fam.decode_step(
                     self.cfg, self.params, self._cache, jnp.asarray(tokens)
                 )
-            # one blocking device-to-host read per running sequence
+            # one blocking device-to-host read for the whole batch
             with obs.span("serve.sample") if obs.ON else obs.OFF:
-                toks = [int(jnp.argmax(logits[s])) for s in slots]
+                picked = np.asarray(_greedy(logits))
             if obs.ON:
-                obs.count("serve.host_syncs", len(toks))
+                obs.count("serve.host_syncs")
             finished = 0
-            for r, tok in zip(reqs, toks):
-                r.generated.append(tok)
+            for r, s in zip(reqs, slots):
+                r.generated.append(int(picked[s]))
                 self.recorder.set_context(r.tenant, r.slo)
                 self.kv.append_tokens(r.req_id, 1)
                 self.recorder.set_context()

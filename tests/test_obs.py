@@ -119,12 +119,12 @@ def test_engine_phases_in_order_and_host_syncs(tracer, tiny_model):
     for p in prompts(cfg, [5, 9, 6]):
         eng.submit(p, max_new=3)
     eng.step()  # compile outside the record
-    before = sum(len(r.generated) for r in eng._requests.values())
     tracer.enable()
     expected = 0
     while eng.waiting or eng.running:
         admits = min(len(eng.waiting), eng.ecfg.max_batch - len(eng.running))
-        expected += admits + len(eng.running) + admits
+        # one read for the step's admissions, one for its decode
+        expected += bool(admits) + bool(len(eng.running) + admits)
         eng.step()
     out = tracer.drain()
     spans = out["spans"]
@@ -136,24 +136,31 @@ def test_engine_phases_in_order_and_host_syncs(tracer, tiny_model):
     steps = [(i, s) for i, s in enumerate(spans) if s[0] == "serve.step"]
     assert [s[4]["step"] for _, s in steps] == list(range(1, 1 + len(steps)))
     assert all(s[3] is None for _, s in steps)
-    frees = 0
+    frees = admitting = decoding = 0
     for i, _ in steps:
         kids = [s[0] for _, s in children(i)]
         admits = [j for j, s in children(i) if s[0] == "serve.admit"]
         assert kids[:len(admits)] == ["serve.admit"] * len(admits)
-        assert kids[len(admits):len(admits) + 2] == ["serve.decode", "serve.sample"]
-        assert set(kids[len(admits) + 2:]) <= {"kv.free", "kv.grow"}
-        frees += kids.count("kv.free")
+        rest = kids[len(admits):]
+        if admits:  # the admitted batch's first tokens, read at once
+            assert rest[0] == "serve.sample"
+            rest = rest[1:]
+            admitting += 1
+        assert rest[:2] == ["serve.decode", "serve.sample"]
+        decoding += 1
+        assert set(rest[2:]) <= {"kv.free", "kv.grow"}
+        frees += rest.count("kv.free")
         for j in admits:
             rid = spans[j][4]["req"]
             tokens = len(eng._requests[rid].prompt)
             assert [(s[0], s[4]) for _, s in children(j)] == [
                 ("kv.grow", {"req": rid}), ("serve.prefill", {"req": rid, "tokens": tokens}),
-                ("serve.sample", {"req": rid}), ("serve.merge", {"req": rid})]
+                ("serve.merge", {"req": rid})]
     assert frees == 3
-    # each served token is one sync: the first at admission, one per decode
-    assert out["counters"]["serve.host_syncs"] == sum(
-        len(r.generated) for r in eng.finished) - before
+    assert admitting > 0
+    # at most two blocking reads a step, however many tokens it serves
+    assert out["counters"]["serve.host_syncs"] == admitting + decoding
+    assert [s[0] for s in spans].count("serve.sample") == admitting + decoding
 
 
 @pytest.mark.parametrize("frag_limit", [CHUNK_SIZE, GMLakeAllocator.TUNED_FRAG_LIMIT],
