@@ -26,10 +26,9 @@ functions. ``named_combined_cum`` sums the four round-4 acceptance terms
 Emits ``BENCH_profile.json`` (via ``benchmarks.common.emit_json``); CI
 runs ``--fast`` mode and uploads the file next to ``BENCH_replay.json``.
 ``benchmarks/compare_replay.py --profile-baseline/--profile-candidate``
-**blocks** on per-term call-count drift and on a take/free core mismatch
-(the ``core`` payload field; round 5) — call counts are load-independent,
-so a silent fallback from the vectorized core to the object path fails CI
-— while the time columns stay informational (warn-annotate only).
+**blocks** on per-term call-count drift — call counts are
+load-independent — while the time columns stay informational
+(warn-annotate only).
 """
 
 from __future__ import annotations
@@ -55,32 +54,17 @@ TERM_SPECS: Dict[str, Sequence[str]] = {
     "stitch_plan": ("GMLakeAllocator._stitch_plan", "GMLakeAllocator._stitch"),
     "hold_sblock": ("GMLakeAllocator._hold_sblock",),
     "destroy_sblock": ("GMLakeAllocator._destroy_sblock",),
-    "apply_activation": ("GMLakeAllocator._apply_activation",),
     "malloc": ("GMLakeAllocator.malloc",),
     "free": ("GMLakeAllocator.free",),
-    # round-5 vectorized passes. Zero ncalls on these while the object-path
-    # terms (apply_activation, take's per-edge code) carry the load is the
-    # signature of a silent fallback to the object core — which is exactly
-    # what the compare_replay.py call-count gate blocks on.
-    # mode-neutral floor term: the take tail's membership count pass.
-    # Object runs resolve _count_take_refs; vectorized runs resolve the
-    # cache-merge trio. One term, either core — the round-5 "improve
-    # >=1.5x like-for-like" floor is read straight off this line.
-    "take_count_pass": (
-        "GMLakeAllocator._count_take_refs",
-        "GMLakeAllocator._count_segs_refs",
-        "GMLakeAllocator._seg_refs",
-    ),
-    "vec_edge_count": (
-        "GMLakeAllocator._seg_refs",
-        "GMLakeAllocator._count_segs_refs",
-    ),
+    # round-5 slot-table passes. The take tail's membership count pass is
+    # the round-5 "improve >=1.5x like-for-like" floor term.
+    "take_count_pass": ("GMLakeAllocator._count_segs_refs",),
     "vec_refcount_apply": (
-        "GMLakeAllocator._apply_activation_vec",
-        "GMLakeAllocator._refs_decrement_vec",
+        "GMLakeAllocator._apply_activation",
+        "GMLakeAllocator._refs_decrement",
     ),
     "vec_purge_compact": (
-        "GMLakeAllocator._purge_refs_vec",
+        "GMLakeAllocator._purge_refs",
         "GMLakeAllocator._compact_dead_log",
     ),
 }
@@ -127,17 +111,8 @@ def _resolve_term_keys() -> Dict[str, List[tuple]]:
     return keys
 
 
-def profile_replay(
-    fast: bool = False,
-    n_requests: Optional[int] = None,
-    alloc_kwargs: Optional[dict] = None,
-) -> dict:
-    """Profile one gmlake serving replay; returns the JSON payload dict.
-
-    ``alloc_kwargs`` passes through to the allocator — the round-5 A/B
-    table profiles ``{"vectorized": False}`` against the default core
-    with identical term definitions.
-    """
+def profile_replay(fast: bool = False, n_requests: Optional[int] = None) -> dict:
+    """Profile one gmlake serving replay; returns the JSON payload dict."""
     from repro.alloc import registry
 
     if n_requests is None:
@@ -146,7 +121,7 @@ def profile_replay(
         PAPER_MODELS["vicuna-13b"], n_requests=n_requests, seed=0
     )
     trace.compiled()  # compile outside the profiled window
-    allocator = registry.create("gmlake", VMMDevice(80 * GB), **(alloc_kwargs or {}))
+    allocator = registry.create("gmlake", VMMDevice(80 * GB))
     gc.collect()
     prof = cProfile.Profile()
     prof.enable()
@@ -207,11 +182,8 @@ def profile_replay(
         "terms": terms,
         "top": top,
         "state_counts": res.state_counts,
-        "hotspot_counters": dict(getattr(allocator, "hotspots", {})),
-        # which take/free core actually ran — compare_replay.py's blocking
-        # call-count tier keys on this to catch silent object-path fallback
-        "core": "vec" if getattr(allocator, "vectorized", False) else "object",
-        "vec_counters": dict(getattr(allocator, "vec_counters", {}) or {}),
+        "hotspot_counters": dict(allocator.hotspots),
+        "vec_counters": dict(allocator.vec_counters),
         "unit": {
             "terms": "per-function ncalls (deterministic) + tottime/cumtime "
             "seconds under cProfile (load-sensitive; compare interleaved "

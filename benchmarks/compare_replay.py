@@ -35,17 +35,14 @@ comparing incomparables.
 
 A third, **hotspot** tier compares named terms from ``BENCH_profile.json``
 files (see ``benchmarks/bench_profile.py``) when both
-``--profile-baseline`` and ``--profile-candidate`` are readable. Since
-round 5 it is two-speed, mirroring the replay tier: per-term *call
-counts* and the recorded take/free ``core`` (``vec``/``object``) are
+``--profile-baseline`` and ``--profile-candidate`` are readable. It is
+two-speed, mirroring the replay tier: per-term *call counts* are
 deterministic for the fixed-seed trace — load cannot move them — so any
-drift **blocks** (subject to ``--annotate-only``); this is what catches a
-silent fallback from the vectorized core to the object path. Per-term
+drift **blocks** (subject to ``--annotate-only``). Per-term
 cumulative-*time* ratios beyond ``--profile-threshold`` (default 1.5x)
 stay informational ``::warning`` annotations — term times are
 load-sensitive, so they exist to *name* the hot term that moved, not to
-block. A baseline predating the ``core`` field keeps the whole tier
-warn-only (no blocking on incomparable schemas).
+block.
 
 A fourth, **chaos** tier compares campaign verdicts from
 ``BENCH_chaos.json`` files (see ``benchmarks/bench_chaos.py``) when both
@@ -155,14 +152,10 @@ def compare_profiles(baseline: dict, candidate: dict, threshold: float):
 
     Returns a list of (kind, term, old, new) findings where ``kind`` is
     ``"time"`` (cumtime ratio past threshold — load-sensitive, never
-    blocks), ``"ncalls"`` (call-count drift — deterministic, so any change
-    is a behaviour change) or ``"core"`` (the recorded take/free core —
-    ``"vec"``/``"object"`` — changed; round 5's silent-fallback tripwire).
+    blocks) or ``"ncalls"`` (call-count drift — deterministic, so any
+    change is a behaviour change).
     """
     findings = []
-    base_core = baseline.get("core")
-    if base_core is not None and base_core != candidate.get("core"):
-        findings.append(("core", "core", base_core, candidate.get("core")))
     base_terms = baseline.get("terms", {})
     cand_terms = candidate.get("terms", {})
     for term, cand_t in cand_terms.items():
@@ -187,13 +180,9 @@ def _profile_tier(profile_baseline, profile_candidate, threshold,
     """Run the hotspot-term tier. Returns the number of blocking findings.
 
     Call counts are deterministic for the fixed-seed trace — load cannot
-    move them — so call-count drift and a take/free core mismatch (the
-    ``core`` field: a silent fallback from the vectorized core to the
-    object path) **block** (subject to ``--annotate-only``). Term *times*
-    stay informational: they are load-sensitive, so they only ever warn.
-    A baseline without a ``core`` field predates the round-5 schema; the
-    whole tier stays warn-only against such a baseline rather than
-    blocking on incomparables.
+    move them — so call-count drift **blocks** (subject to
+    ``--annotate-only``). Term *times* stay informational: they are
+    load-sensitive, so they only ever warn.
     """
     try:
         with open(profile_baseline) as f:
@@ -204,19 +193,10 @@ def _profile_tier(profile_baseline, profile_candidate, threshold,
         print(f"::notice::hotspot-term diff skipped (unreadable profile): {e}")
         return 0
     findings = compare_profiles(base, cand, threshold)
-    legacy_baseline = base.get("core") is None
-    can_block = not annotate_only and not legacy_baseline
-    if legacy_baseline:
-        print("::notice::profile baseline predates the 'core' field: "
-              "hotspot tier is warn-only for this run")
+    can_block = not annotate_only
     blocking = 0
     for kind, term, old, new in findings:
-        if kind == "core":
-            level = "error" if can_block else "warning"
-            blocking += can_block
-            print(f"::{level}::take/free core changed: {old} -> {new} "
-                  f"(silent fallback? vectorized core must stay engaged)")
-        elif kind == "ncalls":
+        if kind == "ncalls":
             level = "error" if can_block else "warning"
             blocking += can_block
             print(f"::{level}::hotspot term {term}: call count changed "
@@ -228,7 +208,7 @@ def _profile_tier(profile_baseline, profile_candidate, threshold,
     if not findings:
         n = len(cand.get("terms", {}))
         print(f"hotspot terms: {n} named terms within {threshold:.2f}x of "
-              f"baseline, call counts and core unchanged")
+              f"baseline, call counts unchanged")
     return blocking
 
 
@@ -418,7 +398,7 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--profile-threshold", type=float, default=1.5,
         help="cumtime ratio that warn-annotates a named hotspot term "
-        "(times never block; call-count/core drift in the same tier does)",
+        "(times never block; call-count drift in the same tier does)",
     )
     ap.add_argument(
         "--serving-baseline", default=None,

@@ -17,7 +17,6 @@ microbenchmarks on hardware that has no CUDA driver.
 
 from __future__ import annotations
 
-import itertools
 import json
 import math
 import random
@@ -25,10 +24,7 @@ from dataclasses import dataclass, field
 from functools import lru_cache
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
-try:  # the array fast paths need numpy; the scalar paths must not
-    import numpy as np
-except ImportError:  # pragma: no cover - exercised via subprocess guard test
-    np = None
+import numpy as np
 
 MB = 1024 * 1024
 GB = 1024 * MB
@@ -102,7 +98,7 @@ class ChunkRun:
     def asarray(self):
         """The view's ids as an int64 array (cached — the backing list is
         immutable by the ChunkRun contract, so the array can never go
-        stale). Requires numpy; the scalar paths never call this."""
+        stale)."""
         arr = self._arr
         if arr is None:
             arr = np.asarray(self.base[self.start : self.stop], dtype=np.int64)
@@ -143,8 +139,7 @@ class ChunkRun:
 
 def _pack_ids_array(a) -> List[Extent]:
     """Vectorized run-length compression of an int64 id array: one compare
-    finds every run break, the Extents are read off the break positions.
-    Output is identical to the scalar scan — same runs, same order."""
+    finds every run break, the Extents are read off the break positions."""
     n = len(a)
     breaks = np.flatnonzero(a[1:] != a[:-1] + 1) + 1
     starts = np.concatenate(([0], breaks))
@@ -157,21 +152,13 @@ def _pack_ids_array(a) -> List[Extent]:
 
 def pack_extents(chunk_ids: Iterable[int]) -> List[Extent]:
     """Compress an ordered chunk-id list into maximal consecutive runs."""
-    if np is not None:
-        if isinstance(chunk_ids, ChunkRun):
-            a = chunk_ids.asarray()
-        else:
-            a = np.fromiter(chunk_ids, dtype=np.int64)
-        if len(a):
-            return _pack_ids_array(a)
-        return []
-    out: List[Extent] = []
-    for cid in chunk_ids:
-        if out and cid == out[-1].stop:
-            out[-1] = Extent(out[-1].start, out[-1].n + 1)
-        else:
-            out.append(Extent(cid, 1))
-    return out
+    if isinstance(chunk_ids, ChunkRun):
+        a = chunk_ids.asarray()
+    else:
+        a = np.fromiter(chunk_ids, dtype=np.int64)
+    if len(a):
+        return _pack_ids_array(a)
+    return []
 
 
 def pack_extent_runs(chunk_runs: Iterable[Iterable[int]]) -> List[Extent]:
@@ -179,19 +166,17 @@ def pack_extent_runs(chunk_runs: Iterable[Iterable[int]]) -> List[Extent]:
 
     Runs merge across boundaries exactly as if the ids were one flat list —
     this is the extent-table builder for stitched blocks, whose chunk ids
-    live in per-member lists. With numpy, member ChunkRuns contribute their
-    cached id arrays and one concatenate feeds the vectorized packer.
+    live in per-member lists. Member ChunkRuns contribute their cached id
+    arrays and one concatenate feeds the vectorized packer.
     """
-    if np is not None:
-        parts = [
-            r.asarray() if isinstance(r, ChunkRun) else np.fromiter(r, dtype=np.int64)
-            for r in chunk_runs
-        ]
-        parts = [p for p in parts if len(p)]
-        if not parts:
-            return []
-        return _pack_ids_array(parts[0] if len(parts) == 1 else np.concatenate(parts))
-    return pack_extents(itertools.chain.from_iterable(chunk_runs))
+    parts = [
+        r.asarray() if isinstance(r, ChunkRun) else np.fromiter(r, dtype=np.int64)
+        for r in chunk_runs
+    ]
+    parts = [p for p in parts if len(p)]
+    if not parts:
+        return []
+    return _pack_ids_array(parts[0] if len(parts) == 1 else np.concatenate(parts))
 
 
 def unpack_extents(extents: Iterable[Extent]) -> List[int]:

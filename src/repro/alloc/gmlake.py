@@ -52,18 +52,18 @@ Hot-path data structures (rounds 1–4 — see docs/ARCHITECTURE.md):
     one segment append per size (no per-member bucket work), and the take
     walk moves a whole-bucket slice into the new plan as one list object
     (no per-member splicing). Each plan freezes its aggregated
-    membership-refcount ``Counter`` (one C-level counting pass per take)
-    alongside ``(segment, generation)`` stamps; any operation that breaks
-    a slice — bucket settle, partial take, split, individual remove —
-    bumps the segment's generation (the plan-generation flag), and
-    ``StitchFree`` destruction appends the dead block to a log that
-    cached Counters replay lazily (``_refs_mark`` — the destroy-dirty
-    watermark) before being trusted, so a frozen plan can never resurrect
-    a destroyed sBlock. When a take consumes exactly a previously-freed
-    cached plan — the dominant serving pattern, a stitched block freed
-    then re-taken at the same size class — ``_hold_sblock`` re-activates
-    the frozen plan in O(members-touched): no candidate walk, no
-    membership recount, no bucket filtering.
+    membership refcounts (one C-level counting pass per take) alongside
+    ``(segment, generation)`` stamps; any operation that breaks a slice —
+    bucket settle, partial take, split, individual remove — bumps the
+    segment's generation (the plan-generation flag), and a cached plan
+    masks ``StitchFree``-destroyed blocks out of its refcounts lazily
+    (``_refs_mark`` — the destroy-dirty watermark) before being trusted,
+    so a frozen plan can never resurrect a destroyed sBlock. When a take
+    consumes exactly a previously-freed cached plan — the dominant
+    serving pattern, a stitched block freed then re-taken at the same
+    size class — ``_hold_sblock`` re-activates the frozen plan in
+    O(members-touched): no candidate walk, no membership recount, no
+    bucket filtering.
   * **Lazy inactive-sBlock delisting** (round 4): a take re-activates tens
     of sBlocks that share members with its candidates, and the paired free
     drops them back; instead of a bucket remove + insert per bounce, the
@@ -85,41 +85,31 @@ Hot-path data structures (rounds 1–4 — see docs/ARCHITECTURE.md):
     bucket when the settled base was exhausted (round 4): consecutive
     same-size takes slice the tail of one persistent sorted list — the
     per-size cursor — instead of re-sorting a pending run each time.
-  * **Vectorized flat-array core** (round 5): every live pBlock/sBlock
-    carries a dense integer slot id into flat numpy arrays (``_VecCore``).
-    Reconciled activity counts live in one int64 array indexed by sBlock
-    slot; membership edges are cached per frozen segment as CSR-style
-    ``edge_ptr``/``edge_sid`` int32 arrays plus their aggregated
-    ``(ref_sids, ref_counts)`` unique form, so a segment that cycles
-    wholesale between pool and plans (the dominant serving pattern) never
-    re-walks its edges. The three refcount passes — the per-take
-    membership count, the reconcile apply/decrement pair, and the
-    destroy-sweep purge — become a handful of vectorized ops
-    (``np.concatenate``/``np.unique``/``np.bincount`` merges, fancy-index
-    scatter, boolean-mask compaction) instead of per-edge iteration,
-    aligning the take/free cycle with the compiled-event design of
-    ``replay_batched``. Destroyed slot ids are quarantined until the
-    dead-log compaction proves no cached array can still name them, which
-    is what makes slot recycling safe. ``vectorized=False`` (or a missing
-    numpy) falls back to the round-4 object path.
+  * **Slot-table refcount core** (round 5): every live pBlock/sBlock
+    carries a dense integer slot id (``_VecCore``). Reconciled activity
+    counts live in one int64 numpy array indexed by sBlock slot, and a
+    plan's frozen refcounts are a ``(slot ids, counts)`` array pair, so
+    the reconcile apply/decrement pair and the destroy purge are a
+    fancy-index scatter and a boolean mask instead of per-edge iteration.
+    The per-take membership count stays one C-level counting pass over
+    the candidates' edges, converted once into that array pair. Destroyed
+    slot ids are quarantined until the dead-log compaction has dropped
+    every cached plan that could still name them, which is what makes
+    slot recycling safe.
 
 All of this is mechanical sympathy only. Replay behaviour — S1–S5 state
 counts, peak active/reserved bytes, OOM points — is bit-identical to the
-seed implementation; ``tests/test_golden_equivalence.py`` pins it,
-``tests/test_plan_identity.py`` additionally pins digest equality with the
-round-4 fast paths force-disabled (``plan_identity=False``), and
-``tests/test_vectorized_core.py`` pins digest parity between the round-5
-array core and the object path (``vectorized=True/False``). The only
-documented *policy* knob is the StitchFree VA budget (``va_budget`` tiers):
-a non-default tier changes eviction decisions — a trade refereed by the
-load-independent modeled device cost, never by wall time.
+seed implementation; ``tests/test_golden_equivalence.py`` pins it. The
+only documented *policy* knob is the StitchFree VA budget (``va_budget``
+tiers): a non-default tier changes eviction decisions — a trade refereed
+by the load-independent modeled device cost, never by wall time.
 """
 
 from __future__ import annotations
 
 import itertools
 from bisect import bisect_left, insort
-from collections import Counter, deque
+from collections import deque
 
 try:  # C-level "count iterable into mapping" (CPython implementation detail)
     from _collections import _count_elements
@@ -133,12 +123,9 @@ from itertools import chain, repeat
 from operator import attrgetter, itemgetter
 from typing import Dict, List, Optional, Tuple
 
-try:  # the vectorized core needs numpy; the object path must not
-    import numpy as np
-except ImportError:  # pragma: no cover - exercised via subprocess guard test
-    np = None
+import numpy as np
 
-_EMPTY_I64 = None if np is None else np.zeros(0, dtype=np.int64)
+_EMPTY_I64 = np.zeros(0, dtype=np.int64)
 
 from .caching_allocator import Allocation, AllocatorOOM, CachingAllocator
 from .chunks import (
@@ -164,7 +151,6 @@ _get_sb_refs = attrgetter("sb_refs")
 _get_split_into = attrgetter("split_into")
 _get_slot = attrgetter("slot")
 _get_block = itemgetter(1)
-_get_active_members = attrgetter("active_members")
 
 
 class PBlock:
@@ -178,10 +164,11 @@ class PBlock:
     ``sb_refs`` is the membership back-reference — every live sBlock
     referencing this pBlock — stored as a **compact flat list** (round 3
     introduced flat arrays; round 4 stores the objects themselves: the
-    refcount loops that consume these lists — Counter building, activation
-    deltas, destroy sweeps — then never pay a registry lookup per entry,
-    and object identity hashes at C speed). Lists stay tiny (typically
-    ~10 entries), so the O(k) removal at destroy is noise.
+    refcount loops that consume these lists — the take's counting pass,
+    single-block activation deltas, destroy sweeps — then never pay a
+    registry lookup per entry, and object identity hashes at C speed).
+    Lists stay tiny (typically ~10 entries), so the O(k) removal at
+    destroy is noise.
 
     ``split_into`` is the deferred-substitution link (round 4): Split sets
     it to the two halves instead of walking every referencing sBlock.
@@ -204,7 +191,7 @@ class PBlock:
         self.sb_refs: List["SBlock"] = []  # live sBlocks referencing this
         self.split_into: Optional[Tuple["PBlock", "PBlock"]] = None
         self.va = va
-        self.slot = -1  # dense id in the vectorized core (-1 = object mode)
+        self.slot = -1  # dense id in the slot tables, set by the allocator
         self._extents: Optional[List[Extent]] = None
 
     @property
@@ -241,48 +228,15 @@ class _Seg:
     re-activation bit-identical. ``owner`` is the sBlock whose
     held/pending free plan the segment currently belongs to, or ``None``
     while pooled.
-
-    Round 5 (vectorized core only) attaches flat membership arrays to the
-    frozen slice itself, so a segment that cycles wholesale between pool
-    and plans never re-walks its edges:
-
-      * ``ref_sids``/``ref_counts`` — the aggregated form the hot path
-        lives on: parallel int64 arrays mapping referencing sBlock slot ->
-        member count (ascending slot order), i.e. the array analogue of
-        the object path's refcount ``Counter``, sized by unique
-        referencing blocks rather than raw edges.
-      * ``edge_sid``/``edge_ptr`` — the raw pBlock→sBlock membership edges
-        in CSR form: ``edge_sid[edge_ptr[i]:edge_ptr[i+1]]`` are the sBlock
-        slot ids referencing member ``entries[i]``. Materialized on demand
-        (``_seg_edges`` — invariant checker, kernels, debugging); dropped
-        (``None``) whenever the edge set changes shape under the cache
-        (owner append, entry append). Cached arrays may name slots whose
-        block has since been destroyed — consumers mask against
-        ``sb_alive`` at the point of use (the invariant checker filters
-        before comparing), so no eager per-destroy purge ever walks the
-        caches.
-      * ``ref_extra`` — owner appends (one ``(slot, count)`` pair per
-        stitch that consumed the slice wholesale) buffered on a plain
-        list; ``_seg_refs`` folds them into the arrays at the next read.
-        Extending a numpy array per append costs ~40x a list append, and
-        stitches append far more often than takes read.
     """
 
-    __slots__ = (
-        "size", "entries", "gen", "owner",
-        "edge_sid", "edge_ptr", "ref_sids", "ref_counts", "ref_extra",
-    )
+    __slots__ = ("size", "entries", "gen", "owner")
 
     def __init__(self, size: int, entries: List[tuple]):
         self.size = size
         self.entries = entries
         self.gen = 0
         self.owner: Optional["SBlock"] = None
-        self.edge_sid = None
-        self.edge_ptr = None
-        self.ref_sids = None
-        self.ref_counts = None
-        self.ref_extra = None
 
     def __repr__(self):
         return f"_Seg(size={self.size >> 20}MB, n={len(self.entries)}, gen={self.gen})"
@@ -302,28 +256,29 @@ class SBlock:
     counter, which un-stamps all members at once (O(1) — the structural pool
     work is deferred to ``GMLakeAllocator._reconcile``). Shell recycling
     keeps ``gen`` monotone across lives so stale stamps stay stale.
-    ``active_members`` is the *reconciled* count of active members, used by
-    the pool/LRU machinery; ``active`` recomputes the truth from member
-    stamps so it is correct even between a free and the next reconcile.
+    The *reconciled* count of active members, used by the pool/LRU
+    machinery, lives in the allocator's slot table (``_VecCore.sb_active``
+    at ``slot``); ``active`` recomputes the truth from member stamps so it
+    is correct even between a free and the next reconcile.
 
     While held (and until its free is reconciled), the block carries its own
     **free plan**: ``_plan`` is a list of ``(_Seg, gen)`` pairs — the very
     segments the take pass consumed, with their plan-generation stamps at
-    freeze time — and ``_refs`` is the plan's membership-refcount Counter
-    (referencing sBlock -> member count, keyed by object). Both are exact
-    at free time because a held member's size and membership set are
-    frozen: splits and new stitches only touch inactive pBlocks, and
-    StitchFree can only destroy a fully-inactive sBlock, which by the
-    activity-exclusivity argument shares no member with any held one.
-    After reconcile, plan and refs are *kept* as a cache (``_refs_mark``
-    remembers the dead-block log position): if every segment is still
-    pooled with a matching generation when the block wins S1 again,
+    freeze time — and ``_refs`` is the plan's membership refcounts as a
+    ``(slot ids, counts)`` array pair (referencing sBlock slot -> member
+    count). Both are exact at free time because a held member's size and
+    membership set are frozen: splits and new stitches only touch inactive
+    pBlocks, and StitchFree can only destroy a fully-inactive sBlock,
+    which by the activity-exclusivity argument shares no member with any
+    held one. After reconcile, plan and refs are *kept* as a cache
+    (``_refs_mark`` remembers the destroy count): if every segment is
+    still pooled with a matching generation when the block wins S1 again,
     ``_hold_sblock`` re-activates the whole plan without a walk or a
     recount (plan-identity reuse).
     """
 
     __slots__ = (
-        "sid", "size", "n_members", "active_members", "gen", "held", "va",
+        "sid", "size", "n_members", "gen", "held", "va",
         "last_use", "pool_listed", "heap_lu", "slot", "_members", "_plan",
         "_refs", "_refs_mark", "_chunks", "_extents",
     )
@@ -334,22 +289,16 @@ class SBlock:
         tick: int,
         va: int = 0,
         size: Optional[int] = None,
-        active_members: Optional[int] = None,
     ):
-        """Plain (non-held) construction — the S2 opportunistic stitch and
-        test paths. Held stitches go through ``GMLakeAllocator._stitch_plan``
-        which fuses member stamping with the segment walk."""
+        """Plain (non-held) construction — the S2 opportunistic stitch.
+        Held stitches go through ``GMLakeAllocator._stitch_plan`` which
+        fuses member stamping with the segment walk."""
         self.sid = next(_ids)
         self._members: List[PBlock] = pblocks
         self.n_members = len(pblocks)
-        # callers that already know the totals pass them in; both are
+        # callers that already know the total pass it in; it is
         # cross-checked against the members by check_invariants()
         self.size = sum(p.size for p in pblocks) if size is None else size
-        self.active_members = (
-            sum(1 for p in pblocks if p.active)
-            if active_members is None
-            else active_members
-        )
         self.gen = 0
         self.held = False
         self.va = va
@@ -357,9 +306,9 @@ class SBlock:
         self.pool_listed = False
         self.heap_lu: Optional[int] = None  # last_use of this block's live
         # LRU-heap entry, or None — dedups crossing pushes (round 4)
-        self.slot = -1  # dense id in the vectorized core (-1 = object mode)
+        self.slot = -1  # dense id in the slot tables, set by the allocator
         self._plan: Optional[List[Tuple[_Seg, int]]] = None
-        self._refs: Optional[Dict["SBlock", int]] = None
+        self._refs = None
         self._refs_mark = 0
         self._chunks: Optional[List[int]] = None
         self._extents: Optional[List[Extent]] = None
@@ -442,63 +391,35 @@ def _count_entry_sids(counter: dict, entries: List[tuple]) -> None:
     )
 
 
-def _merge_id_parts(parts_s: List, parts_c: List):
-    """Sum parallel ``(ids, counts)`` array parts into one ascending
-    unique pair.
-
-    Sort-based: O(m log m) in the total part length m — and effectively
-    O(m), since each part arrives ascending and the stable sort is a
-    run-merge — **independent of the slot-table size**. (The obvious
-    ``bincount`` + ``nonzero`` merge is O(table) per call, which comes to
-    dominate the take tail once the table outgrows the per-take working
-    set — exactly what happens over a long serving replay.) Duplicate ids
-    within or across parts sum exactly; int64 throughout. Callers
-    guarantee at least one non-empty part.
-    """
-    s = np.concatenate(parts_s)
-    c = np.concatenate(parts_c)
-    order = s.argsort(kind="stable")
-    s = s[order]
-    c = c[order]
-    lead = np.empty(s.size, dtype=bool)
-    lead[0] = True
-    np.not_equal(s[1:], s[:-1], out=lead[1:])
-    idx = lead.nonzero()[0]
-    if idx.size == s.size:  # no duplicates anywhere: already reduced
-        return s, c
-    return s[idx], np.add.reduceat(c, idx)
-
-
 class _VecCore:
-    """Flat-array state for the vectorized take/free core (round 5).
+    """Flat-array slot tables for the take/free refcount core (round 5).
 
     Two dense integer id spaces, managed with free lists so arrays stay
     O(live blocks), not O(creations):
 
       * **sBlock slots** index three parallel structures: ``sb_active``
-        (int64 — the reconciled active-member count, the array analogue of
-        ``SBlock.active_members``, which goes *stale* in vectorized mode),
-        ``sb_alive`` (bool — live vs destroyed, the purge mask), and
-        ``sb_by_slot`` (slot -> SBlock, for resolving zero-crossings back
-        to objects).
+        (int64 — the reconciled active-member count), ``sb_alive`` (bool —
+        live vs destroyed, the purge mask), and ``sb_by_slot`` (slot ->
+        SBlock, for resolving zero-crossings back to objects).
       * **pBlock slots** are a plain dense id space (no arrays index them
-        today); they exist so every block has a stable small-int identity
-        for edge arrays and invariant checks.
+        today); they give every block a stable small-int identity for
+        invariant checks.
 
-    Slot recycling safety: cached segment/plan ref arrays may name a slot
-    long after its block was destroyed (they are purged lazily against
-    ``sb_alive``). A destroyed slot is therefore **quarantined** — not
-    returned to the free list — until ``compact_sb()``, which the
-    allocator calls only from ``_compact_dead_log`` after dropping every
-    cached array that could still name an old slot. Between compactions a
-    quarantined slot stays dead in ``sb_alive``, so aliveness masks purge
-    it from any cache; after a compaction no cache names it at all. That
+    Slot recycling safety: a cached plan's ref arrays (``SBlock._refs``)
+    may name a slot long after its block was destroyed (they are purged
+    lazily against ``sb_alive``). A destroyed slot is therefore
+    **quarantined** — not returned to the free list — until
+    ``compact_sb()``, which the allocator calls only from
+    ``_compact_dead_log`` after dropping every cached plan that could
+    still name an old slot. Between compactions a quarantined slot stays
+    dead in ``sb_alive``, so aliveness masks purge it from any cached
+    plan; after a compaction no cached plan names it at all. That
     two-phase release is what makes fancy-index scatter (which requires
     unique indices) sound against recycled ids.
 
     ``deaths`` is a monotone destroy counter used as a cache version stamp
-    (``_Seg.ref_mark`` / ``SBlock._refs_mark``): unlike the dead-block
-    *log* it is never reset, so stale marks are never ambiguous.
+    (``SBlock._refs_mark``): unlike the quarantine list it is never reset,
+    so stale marks are never ambiguous.
     """
 
     INITIAL_SLOTS = 64
@@ -551,6 +472,11 @@ class _VecCore:
         self.sb_by_slot[slot] = None
         self._sb_quarantine.append(slot)
         self.deaths += 1
+
+    @property
+    def quarantined(self) -> int:
+        """Destroyed sBlock slots awaiting ``compact_sb``."""
+        return len(self._sb_quarantine)
 
     def compact_sb(self) -> None:
         q = self._sb_quarantine
@@ -630,9 +556,9 @@ class _IndexedPool:
     def _settled(self, size: int) -> List[tuple]:
         """The sorted bucket for ``size``, merging loose runs and segments.
 
-        Merged segments die (``refs = None``): their entries now belong to
-        the settled bucket and can be cherry-picked, so any cached plan
-        referencing them must fall back to the recounting path.
+        Merged segments die (their ``gen`` is bumped): their entries now
+        belong to the settled bucket and can be cherry-picked, so any
+        cached plan referencing them must fall back to the recounting path.
         """
         bucket = self._buckets[size]
         loose = self._loose.pop(size, None)
@@ -761,14 +687,12 @@ class _InactiveSBlocks(_IndexedPool):
     the eager representation for iteration/invariant checks.
 
     The staleness filter reads the reconciled active-member count through
-    ``active_of`` (round 5): the object path reads
-    ``SBlock.active_members``, the vectorized core reads its
-    ``sb_active`` slot — the attribute goes stale in that mode.
+    ``active_of``, the allocator's ``sb_active`` slot lookup.
     """
 
     __slots__ = ("_active_of",)
 
-    def __init__(self, active_of=_get_active_members):
+    def __init__(self, active_of):
         super().__init__()
         self._active_of = active_of
 
@@ -898,22 +822,12 @@ class GMLakeAllocator:
     an eager implementation would have. Reconciliation timing is therefore
     unobservable, which is what keeps replay digests bit-identical.
 
-    ``plan_identity=False`` force-disables the round-4 fast paths (frozen
-    segment Counters, wholesale segment reuse, cached-plan re-activation):
-    every consumption re-counts membership from the sid arrays. Behaviour
-    is bit-identical either way — ``tests/test_plan_identity.py`` pins it.
-
-    ``vectorized`` selects the round-5 flat-array refcount core (default:
-    on when numpy is importable; requesting it without numpy falls back to
-    the object path and counts a ``numpy_fallback``). Behaviour is
-    bit-identical either way — ``tests/test_vectorized_core.py`` pins it.
-
     ``va_budget`` is the documented StitchFree policy knob: a tier name
     from ``VA_BUDGET_TIERS`` (``"paper"``/``"tight"``/``"speed"``), a float
-    multiple of device capacity, or an absolute byte count (int). The
-    legacy ``sblock_va_budget`` (absolute bytes) wins when both are given.
-    Non-default tiers trade peak stitched VA (``peak_sblock_va``) against
-    modeled device cost — see ``VA_BUDGET_TIERS``.
+    multiple of device capacity, or an absolute byte count (int); the
+    resolved byte budget is ``sblock_va_budget``. Non-default tiers trade
+    peak stitched VA (``peak_sblock_va``) against modeled device cost —
+    see ``VA_BUDGET_TIERS``.
     """
 
     name = "gmlake"
@@ -928,30 +842,26 @@ class GMLakeAllocator:
     #: Destroyed-sBlock shells kept for recycling (round 4).
     MAX_SHELLS = 64
 
-    #: Destroyed-block log length that triggers compaction (drop cached
-    #: plans, clear the log) so memory stays O(live), not O(destroys).
+    #: Quarantined (destroyed, not yet recyclable) sBlock slots that trigger
+    #: compaction (drop cached plans, recycle the slots) so memory stays
+    #: O(live), not O(destroys).
     DEAD_LOG_LIMIT = 4096
 
     def __init__(
         self,
         device: VMMDevice,
         frag_limit: int = TUNED_FRAG_LIMIT,
-        sblock_va_budget: Optional[int] = None,
         record_timeline: bool = False,
-        plan_identity: bool = True,
         recovery: Optional[bool] = None,
         deferred_unmap: Optional[bool] = None,
-        vectorized: Optional[bool] = None,
         va_budget=None,
     ):
         self.device = device
         self.frag_limit = frag_limit
         # paper §4.2.3: VA for stitched blocks is capped; LRU StitchFree past
-        # it. Resolution order: legacy absolute bytes, then the policy knob
-        # (tier name / capacity multiple / absolute bytes), then the default
-        # "paper" tier (4x capacity).
-        self.sblock_va_budget = self._resolve_va_budget(sblock_va_budget, va_budget)
-        self.plan_identity = plan_identity
+        # it. The policy knob (tier name / capacity multiple / absolute
+        # bytes) defaults to the "paper" tier (4x capacity).
+        self.sblock_va_budget = self._resolve_va_budget(va_budget)
         self.stats = AllocatorStats(record_timeline=record_timeline)
         self.state_counts: Dict[str, int] = {f"S{i}": 0 for i in range(1, 6)}
         #: mallocs that handed out stitched memory (an sBlock: S1 re-use of
@@ -965,39 +875,16 @@ class GMLakeAllocator:
         }
         self.stats.counters = self.hotspots
 
-        #: round-5 vectorized-core observability (diagnostics only; never
+        #: round-5 slot-table observability (diagnostics only; never
         #: digest material). Surfaced through ``ReplayResult.vec_counters``
         #: and ``ServeEngine.memory_report()`` exactly like
         #: ``elastic_counters`` / recovery summaries — no side channels.
         self.vec_counters: Dict[str, int] = {
-            "enabled": 0,
-            "numpy_fallback": 0,  # vectorized requested but numpy missing
-            "seg_cache_builds": 0,  # edge arrays built from object lists
-            "seg_cache_appends": 0,  # owner-append updates of cached arrays
-            "ref_purges": 0,  # aliveness-mask compactions of cached arrays
+            "ref_purges": 0,  # aliveness-mask compactions of cached plans
             "slot_grows": 0,  # slot-table doublings
             "dead_compactions": 0,  # quarantined-slot recycles
         }
-        if vectorized is None:
-            self.vectorized = np is not None
-        else:
-            self.vectorized = bool(vectorized) and np is not None
-            if vectorized and np is None:
-                self.vec_counters["numpy_fallback"] = 1
-        if self.vectorized:
-            self.vec_counters["enabled"] = 1
-            self._vec_core = _VecCore(self.vec_counters)
-            # mode binding: the refcount passes are bound per instance so
-            # the hot path never re-tests the mode (same pattern as
-            # AllocatorStats.__post_init__'s timeline-free fast variants)
-            self._apply_activation = self._apply_activation_vec
-            self._refs_decrement = self._refs_decrement_vec
-            self._purge_refs = self._purge_refs_vec
-            self._activate_p = self._activate_p_vec
-            self._deactivate_p = self._deactivate_p_vec
-            self._active_of = self._active_of_vec
-        else:
-            self._vec_core = None
+        self._vec_core = _VecCore(self.vec_counters)
         self.stats.vec_counters = self.vec_counters
         #: high-water mark of stitched VA (the va_budget trade-off metric)
         self.peak_sblock_va = 0
@@ -1017,9 +904,6 @@ class GMLakeAllocator:
         # bumped (members read as inactive) but pools/refcounts are stale.
         self._pending_frees: List[SBlock] = []
         self._shells: List[SBlock] = []  # recycled sBlock shells
-        # append-only log of destroyed sBlocks; cached plan Counters are
-        # purged lazily against it (see SBlock._refs_mark / _purge_refs)
-        self._dead_refs: List[SBlock] = []
         self._sblock_va_bytes = 0
         self._chunk_bytes = 0  # physical chunks created (reserved by VMS pool)
         self._tick = 0
@@ -1042,16 +926,13 @@ class GMLakeAllocator:
             device, recovery=self._recovery_on, event_log=self.event_log
         )
 
-    def _resolve_va_budget(self, sblock_va_budget, va_budget):
-        """Resolve the StitchFree VA budget from the two knobs.
+    def _resolve_va_budget(self, va_budget):
+        """Resolve the StitchFree VA budget in bytes.
 
-        ``sblock_va_budget`` (legacy, absolute bytes) wins when given.
         ``va_budget`` accepts a tier name from ``VA_BUDGET_TIERS``, a float
         (multiple of device capacity) or an int (absolute bytes); the
         ``"speed"`` tier maps to +inf (StitchFree never fires).
         """
-        if sblock_va_budget is not None:
-            return sblock_va_budget
         capacity = self.device.capacity_bytes
         if va_budget is None:
             return 4 * capacity
@@ -1085,30 +966,26 @@ class GMLakeAllocator:
             self.peak_sblock_va = va
 
     # ------------------------------------------------------------------
-    # activity accessors (mode-bound, round 5)
-    # ------------------------------------------------------------------
-    def _active_of(self, s: SBlock) -> int:
-        """Reconciled active-member count (object path: the attribute)."""
-        return s.active_members
-
-    def _active_of_vec(self, s: SBlock) -> int:
-        """Reconciled active-member count (vectorized: the slot array —
-        ``active_members`` is stale in this mode)."""
-        return int(self._vec_core.sb_active[s.slot])
-
-    # ------------------------------------------------------------------
     # activity transitions
     # ------------------------------------------------------------------
+    def _active_of(self, s: SBlock) -> int:
+        """Reconciled active-member count: the block's slot in ``sb_active``."""
+        return int(self._vec_core.sb_active[s.slot])
+
     def _activate_p(self, p: PBlock) -> None:
         """Inactive -> directly active: leave the pool, bump member refcounts.
 
         Single-block handout (S1 pBlock / S2): O(log bucket + |p.sb_refs|).
+        ``sb_refs`` is a tiny object list (~10 entries): the single-block
+        transitions never had an array-shaped cost, only the batched
+        passes did.
         """
         assert not p.active
         self._inactive_p.remove(p)
         p.direct = True
+        act = self._vec_core.sb_active
         for s in p.sb_refs:
-            s.active_members += 1  # re-listing is lazy: exact() filters
+            act[s.slot] += 1  # re-listing is lazy: exact() filters
 
     def _deactivate_p(self, p: PBlock) -> None:
         """Directly active -> inactive. The single-block inverse.
@@ -1117,38 +994,6 @@ class GMLakeAllocator:
         deferred ones, and a zero-crossing pushed here or at reconcile
         carries the same (last_use, sid) either way.
         """
-        assert p.direct
-        p.direct = False
-        self._inactive_p.add(p)
-        heap = self._lru_heap
-        inactive_s = self._inactive_s
-        for s in p.sb_refs:
-            m = s.active_members - 1
-            s.active_members = m
-            assert m >= 0
-            if m == 0:
-                if s.heap_lu != s.last_use:
-                    s.heap_lu = s.last_use
-                    heappush(heap, (s.last_use, s.sid))
-                if not s.pool_listed:
-                    s.pool_listed = True
-                    inactive_s.add(s)
-
-    def _activate_p_vec(self, p: PBlock) -> None:
-        """``_activate_p`` against the slot array (vectorized core).
-
-        ``sb_refs`` stays a tiny object list (~10 entries) in both modes —
-        the single-block transitions never had an array-shaped cost; only
-        the batched passes did.
-        """
-        assert not p.active
-        self._inactive_p.remove(p)
-        p.direct = True
-        act = self._vec_core.sb_active
-        for s in p.sb_refs:
-            act[s.slot] += 1
-
-    def _deactivate_p_vec(self, p: PBlock) -> None:
         assert p.direct
         p.direct = False
         self._inactive_p.add(p)
@@ -1169,29 +1014,17 @@ class GMLakeAllocator:
                     inactive_s.add(s)
 
     def _purge_refs(self, s: SBlock) -> None:
-        """Drop destroyed sBlocks from a cached plan's refcount Counter.
+        """Drop destroyed sBlocks from a cached plan's refcount arrays.
 
         Destruction removes the dead block from every member's ``sb_refs``;
-        a reconciled block's cached ``_refs`` Counter froze those counts, so
-        before the S1 fast path trusts it, the dead-block log is replayed
-        from ``_refs_mark`` (the destroy-dirty watermark set at reconcile).
-        O(destroys since the block was reconciled) — typically zero or one.
+        a reconciled block's cached ``_refs`` froze those counts, so before
+        the S1 fast path trusts them, one aliveness mask over the
+        ``(sids, counts)`` pair drops every destroyed slot — destroyed
+        slots stay dead in ``sb_alive`` until every cached plan has had a
+        chance to drop them (the quarantine rule), so masking is exact at
+        any time. ``_refs_mark`` holds the monotone ``deaths`` stamp, so a
+        plan with no destroy since its reconcile skips the mask.
         """
-        dead = self._dead_refs
-        n = len(dead)
-        mark = s._refs_mark
-        if mark < n:
-            refs = s._refs
-            for r in dead[mark:]:
-                refs.pop(r, None)
-            s._refs_mark = n
-
-    def _purge_refs_vec(self, s: SBlock) -> None:
-        """Vectorized ``_purge_refs``: one aliveness mask over the cached
-        ``(ref_sids, ref_counts)`` plan arrays instead of a log replay —
-        destroyed slots stay dead in ``sb_alive`` until every cache has had
-        a chance to drop them (the quarantine rule), so masking is exact at
-        any time. ``_refs_mark`` holds the monotone ``deaths`` stamp."""
         core = self._vec_core
         deaths = core.deaths
         if s._refs_mark != deaths:
@@ -1204,119 +1037,27 @@ class GMLakeAllocator:
             s._refs_mark = deaths
 
     # ------------------------------------------------------------------
-    # vectorized membership counting (round 5)
+    # membership counting
     # ------------------------------------------------------------------
-    def _seg_refs(self, seg: _Seg):
-        """The segment's aggregated membership refcounts as parallel arrays
-        ``(ref_sids, ref_counts)`` — slot ids of referencing sBlocks and how
-        many of this slice's members each references.
-
-        Checker/introspection surface — the hot take path counts raw
-        edges directly (see ``_count_segs_refs`` for why). Cache hit:
-        fold any buffered owner appends, then reuse verbatim. The
-        returned arrays may still name slots destroyed since the cache
-        was built — destroys never walk the caches; consumers mask
-        against ``sb_alive`` at the point of use (the invariant checker
-        filters before comparing). Miss: one C-level counting pass over
-        the members' ``sb_refs`` chains (the same ``_count_elements``
-        machinery as the object path) feeds two small ``fromiter`` calls
-        — the arrays built are sized by *unique referencing blocks*,
-        never by raw edges, and a fresh build is alive-only by
-        construction (``_destroy_sblock`` scrubs ``sb_refs`` eagerly).
-        """
-        sids = seg.ref_sids
-        if sids is not None:
-            extra = seg.ref_extra
-            if extra is not None:
-                # fold buffered owner appends (same end-of-array order an
-                # eager per-append extension would have produced)
-                n = sids.size
-                k = len(extra)
-                folded_s = np.empty(n + k, dtype=np.int64)
-                folded_c = np.empty(n + k, dtype=np.int64)
-                folded_s[:n] = sids
-                folded_c[:n] = seg.ref_counts
-                for i, (sl, c) in enumerate(extra, n):
-                    folded_s[i] = sl
-                    folded_c[i] = c
-                sids = folded_s
-                seg.ref_sids = folded_s
-                seg.ref_counts = folded_c
-                seg.ref_extra = None
-            return sids, seg.ref_counts
-        d: Dict[SBlock, int] = {}
-        _count_elements(
-            d,
-            chain.from_iterable(e[1].sb_refs for e in seg.entries),
-        )
-        n = len(d)
-        if n:
-            sids = np.fromiter(map(_get_slot, d.keys()), np.int64, count=n)
-            counts = np.fromiter(d.values(), np.int64, count=n)
-            # slot order (ascending) — the same order the merge output has
-            # (nonzero of a slot-indexed accumulator), so downstream
-            # ordering never depends on which path produced the arrays
-            order = np.argsort(sids)
-            sids = sids[order]
-            counts = counts[order]
-        else:
-            sids = _EMPTY_I64
-            counts = _EMPTY_I64
-        seg.ref_sids = sids
-        seg.ref_counts = counts
-        self._vec_core.counters["seg_cache_builds"] += 1
-        return sids, counts
-
-    def _seg_edges(self, seg: _Seg):
-        """The slice's raw pBlock→sBlock membership edges in CSR form
-        (``edge_sid``/``edge_ptr``) — materialized on demand and cached.
-
-        The hot path only ever needs the aggregated ``(ref_sids,
-        ref_counts)`` form, so the per-edge arrays are built lazily (the
-        invariant checker cross-validates them against the aggregate;
-        kernels/debugging can walk them). Every cache-invalidation site
-        drops both forms together, but destroys do NOT walk caches — a
-        cached CSR may predate ``sb_refs`` scrubbing. Callers needing an
-        authoritative edge list must drop ``edge_sid``/``edge_ptr`` first
-        (the invariant checker does).
-        """
-        es = seg.edge_sid
-        if es is not None:
-            return es, seg.edge_ptr
-        edges: List[SBlock] = []
-        ptr = [0]
-        pa = ptr.append
-        for e in seg.entries:
-            edges += e[1].sb_refs
-            pa(len(edges))
-        es = np.fromiter(map(_get_slot, edges), np.int32, count=len(edges))
-        seg.edge_sid = es
-        seg.edge_ptr = np.asarray(ptr, dtype=np.int32)
-        return es, seg.edge_ptr
-
     def _count_segs_refs(self, segs: List[_Seg]):
-        """The take tail's membership count, vectorized core: ONE C-level
-        counting pass over the candidate set's raw edges, converted once
-        into the ``(sids, counts)`` array pair that drives every
-        downstream array pass (activation scatter, reconcile decrement,
-        aliveness masking).
+        """The take tail's membership count: ONE C-level counting pass over
+        the candidate set's raw edges, converted once into the
+        ``(sids, counts)`` array pair that drives every downstream array
+        pass (activation scatter, reconcile decrement, aliveness masking).
 
-        Deliberately the same counting *kernel* as the object path
-        (``_count_take_refs``). A per-segment cached-aggregate merge was
-        built, measured and rejected for this spot: at serving scale a
-        take's candidate set is ~10 slices / ~1k edges compressing to
-        ~125 unique referencing blocks, and one ``_count_elements`` walk
-        at tens of ns/edge beats any numpy merge whose per-op constants
-        are ~1-2 µs — the measured crossover sits near ~5k edges per
-        take, which the serving replay never approaches (BENCHMARKS.md,
-        round 5). The arrays win where state is long-lived and batched —
-        the refcount pair, plan purges, the destroy sweep — so the count
-        pass feeds them without itself merging arrays. Fresh counts are
-        alive-only by construction (``_destroy_sblock`` scrubs
-        ``sb_refs`` eagerly), so no aliveness mask is needed; the output
-        is deliberately unsorted (no consumer is order-sensitive). The
-        per-segment aggregate/CSR caches remain the invariant checker's
-        and introspection's domain (``_seg_refs`` / ``_seg_edges``).
+        A per-segment cached-aggregate merge was built, measured and
+        rejected for this spot: at serving scale a take's candidate set is
+        ~10 slices / ~1k edges compressing to ~125 unique referencing
+        blocks, and one ``_count_elements`` walk at tens of ns/edge beats
+        any numpy merge whose per-op constants are ~1-2 µs — the measured
+        crossover sits near ~5k edges per take, which the serving replay
+        never approaches (BENCHMARKS.md, round 5). The arrays win where
+        state is long-lived and batched — the refcount pair, plan purges,
+        the destroy sweep — so the count pass feeds them without itself
+        merging arrays. Fresh counts are alive-only by construction
+        (``_destroy_sblock`` scrubs ``sb_refs`` eagerly), so no aliveness
+        mask is needed; the output is deliberately unsorted (no consumer
+        is order-sensitive).
         """
         edges: List[SBlock] = []
         for seg in segs:
@@ -1339,9 +1080,9 @@ class GMLakeAllocator:
         plan — the very segments its last free re-inserted into the pool —
         is still entirely frozen and pooled, re-activating it is one
         ``remove_seg`` per size plus a stamping walk: no candidate scan, no
-        bucket filtering, no membership recount (each segment's Counter is
-        exact by the frozen-slice invariant once the dead-sid log is
-        replayed). Slow path: the round-2 scheme — one generation bump, one
+        bucket filtering, no membership recount (the plan's refcount arrays
+        are exact by the frozen-slice invariant once destroyed slots are
+        masked out). Slow path: the round-2 scheme — one generation bump, one
         stamp per member, one bucket filter per member size, one refcount
         pass per size — which also rebuilds fresh frozen segments so the
         next cycle is fast again.
@@ -1354,15 +1095,15 @@ class GMLakeAllocator:
         s.pool_listed = False
         gen = s.gen
         plan = s._plan
-        if plan is not None and self.plan_identity:
+        if plan is not None:
             members = s.members()  # resolves splits (which also bump seg gens)
             if all(
                 seg.gen == g and seg.owner is None for seg, g in plan
             ) and sum(len(seg.entries) for seg, _g in plan) == len(members):
                 # every slice of the cached plan survived untouched: the pool
                 # still holds exactly this block's members, in these slices,
-                # and the frozen refcount Counter is exact modulo destroyed
-                # blocks — which the dead-log replay removes
+                # and the frozen refcounts are exact modulo destroyed
+                # blocks — which the aliveness purge removes
                 self._purge_refs(s)
                 remove_seg = self._inactive_p.remove_seg
                 for seg, _g in plan:
@@ -1387,71 +1128,44 @@ class GMLakeAllocator:
             else:
                 entries.append((p.pid, p))
         new_plan: List[Tuple[_Seg, int]] = []
-        if self.vectorized:
-            segs: List[_Seg] = []
-            for size, entries in by_size.items():
-                pools[size >= limit].remove_batch(size, {e[0] for e in entries})
-                seg = _Seg(size, entries)
-                seg.owner = s
-                new_plan.append((seg, 0))
-                segs.append(seg)
-            # one C counting pass over the fresh segments, converted once
-            # into the array pair the vectorized refcount passes consume
-            refs = self._count_segs_refs(segs)
-        else:
-            refs: Dict[SBlock, int] = {}
-            for size, entries in by_size.items():
-                pools[size >= limit].remove_batch(size, {e[0] for e in entries})
-                _count_entry_sids(refs, entries)
-                seg = _Seg(size, entries)
-                seg.owner = s
-                new_plan.append((seg, 0))
+        segs: List[_Seg] = []
+        for size, entries in by_size.items():
+            pools[size >= limit].remove_batch(size, {e[0] for e in entries})
+            seg = _Seg(size, entries)
+            seg.owner = s
+            new_plan.append((seg, 0))
+            segs.append(seg)
+        # one C counting pass over the fresh segments, converted once
+        # into the array pair the refcount passes consume
+        refs = self._count_segs_refs(segs)
         self._apply_activation(refs)
         s._plan = new_plan
         s._refs = refs
 
-    def _apply_activation(self, refs: Dict["SBlock", int]) -> None:
+    def _apply_activation(self, refs) -> None:
         """Apply aggregated +delta membership refcounts (activation side).
 
-        ``refs`` maps referencing sBlock -> count (objects are the Counter
-        keys, so no registry resolution happens here at all). Re-activated
-        blocks are *not* removed from the inactive pool — delisting is lazy
-        (see ``_InactiveSBlocks``) — so this is a pure integer pass.
+        ``refs`` is the plan's ``(sids, counts)`` array pair; slot ids are
+        unique within a plan, so one fancy-index scatter-add applies the
+        whole batch. Re-activated blocks are *not* removed from the
+        inactive pool — delisting is lazy (see ``_InactiveSBlocks``) — so
+        this is a pure integer pass.
         """
-        for s, d in refs.items():
-            s.active_members += d
-
-    def _apply_activation_vec(self, refs) -> None:
-        """Vectorized ``_apply_activation``: ``refs`` is the plan's
-        ``(sids, counts)`` array pair; slot ids are unique within a plan,
-        so one fancy-index scatter-add applies the whole batch."""
         sids, counts = refs
         if sids.size:
             self._vec_core.sb_active[sids] += counts
 
     def _refs_decrement(self, refs, zeros_append) -> None:
-        """Apply a freed plan's refcount decrements (object path).
+        """Apply a freed plan's refcount decrements.
 
-        Collects blocks whose reconciled count crossed zero into ``zeros``
-        via ``zeros_append`` — the caller does the heap/pool listing, which
-        is shared between both modes. Counts only shrink during a reconcile
-        batch, so each block crosses zero at most once across the batch and
-        the collected order equals the crossing order.
+        One gather, one subtract, one scatter over the slot array; only
+        zero-crossings come back to the object world (via ``sb_by_slot``),
+        collected through ``zeros_append`` for the caller's LRU/pool
+        listing. Counts only shrink during a reconcile batch, so each block
+        crosses zero at most once across the batch. Non-negativity is
+        covered globally by the invariant checker ("slot activity
+        drifted"), so the hot path carries no reduction.
         """
-        for r, d in refs.items():
-            m = r.active_members - d
-            r.active_members = m
-            assert m >= 0
-            if m == 0:
-                zeros_append(r)
-
-    def _refs_decrement_vec(self, refs, zeros_append) -> None:
-        """Vectorized ``_refs_decrement``: one gather, one subtract, one
-        scatter over the slot array; only zero-crossings come back to the
-        object world (via ``sb_by_slot``) for LRU/pool listing. The object
-        path's per-entry non-negativity assert is covered globally by the
-        invariant checker ("slot activity drifted"), so the hot path
-        carries no reduction."""
         sids, counts = refs
         if not sids.size:
             return
@@ -1491,12 +1205,8 @@ class GMLakeAllocator:
         heap = self._lru_heap
         inactive_s_add = self._inactive_s.add
         # the cache-freshness stamp written to each reconciled plan: the
-        # dead-log position (object path) or the monotone destroy counter
-        # (vectorized path — see _VecCore.deaths)
-        if self.vectorized:
-            dead_mark = self._vec_core.deaths
-        else:
-            dead_mark = len(self._dead_refs)
+        # monotone destroy counter (see _VecCore.deaths)
+        dead_mark = self._vec_core.deaths
         refs_decrement = self._refs_decrement
         zeros: List[SBlock] = []
         zeros_append = zeros.append
@@ -1517,9 +1227,8 @@ class GMLakeAllocator:
                 pool._count += n
                 pool.bytes += size * n
             s._refs_mark = dead_mark  # refs cached for plan-identity re-holds
-            # decrement from the plan's frozen refcounts (Counter keyed by
-            # the referencing sBlocks themselves, or the slot-array pair):
-            # counts only shrink, so zero-crossings are batch-order
+            # decrement from the plan's frozen refcounts (the slot-array
+            # pair): counts only shrink, so zero-crossings are batch-order
             # independent and land on whichever decrement is last
             refs_decrement(s._refs, zeros_append)
         for r in zeros:
@@ -1545,8 +1254,7 @@ class GMLakeAllocator:
         self._pblocks[p.pid] = p
         self._chunk_bytes += p.size
         p.direct = True  # handed out or immediately stitched by the caller
-        if self.vectorized:
-            p.slot = self._vec_core.acquire_pb()
+        p.slot = self._vec_core.acquire_pb()
         return p
 
     def _split_parts(self, p: PBlock, first_size: int) -> Tuple[PBlock, PBlock]:
@@ -1570,11 +1278,10 @@ class GMLakeAllocator:
         b = PBlock(chunks[k:])
         self._pblocks[a.pid] = a
         self._pblocks[b.pid] = b
-        if self.vectorized:
-            core = self._vec_core
-            core.release_pb(p.slot)  # pb slots have no caches: recycle now
-            a.slot = core.acquire_pb()
-            b.slot = core.acquire_pb()
+        core = self._vec_core
+        core.release_pb(p.slot)  # pb slots have no caches: recycle now
+        a.slot = core.acquire_pb()
+        b.slot = core.acquire_pb()
         # two new VA reservations + remap (charged to the device model)
         self.device.vmm_split_remap(k, len(b.chunks))
         refs = p.sb_refs
@@ -1600,31 +1307,21 @@ class GMLakeAllocator:
         return a, b
 
     def _stitch(
-        self,
-        pblocks: List[PBlock],
-        total_size: Optional[int] = None,
-        active_members: Optional[int] = None,
+        self, pblocks: List[PBlock], total_size: int, active_members: int
     ) -> SBlock:
         """Paper's Stitch, non-held form: the S2 opportunistic stitch whose
         members keep their own state. Held stitches (S3/S4) go through
-        ``_stitch_plan``. Re-maps, no Create."""
-        if total_size is None:
-            total_size = sum(p.size for p in pblocks)
+        ``_stitch_plan``. Re-maps, no Create. The caller passes the
+        members' total size and how many of them are active."""
         n = total_size // CHUNK_SIZE  # == total member chunk count
         self.device.vmm_map_existing(n)
-        s = SBlock(
-            pblocks, tick=self._tick, size=total_size,
-            active_members=active_members,
-        )
+        s = SBlock(pblocks, tick=self._tick, size=total_size)
         self._sblocks[s.sid] = s
-        if self.vectorized:
-            # the constructor already appended s to each member's sb_refs;
-            # mirror the freshly computed count into the slot array (the
-            # attribute goes stale from here on)
-            s.slot = self._vec_core.acquire_sb(s)
-            self._vec_core.sb_active[s.slot] = s.active_members
+        # the constructor already appended s to each member's sb_refs
+        s.slot = self._vec_core.acquire_sb(s)
+        self._vec_core.sb_active[s.slot] = active_members
         self._note_sblock_va(s.size)
-        if self._active_of(s) == 0:
+        if active_members == 0:
             s.pool_listed = True
             s.heap_lu = s.last_use
             self._inactive_s.add(s)
@@ -1636,14 +1333,14 @@ class GMLakeAllocator:
         self,
         plan: Dict[int, _Seg],
         total_size: int,
-        refs: Dict["SBlock", int],
+        refs,
         members: List[PBlock],
     ) -> SBlock:
         """Stitch and hand out the take pass's segments (S3/S4).
 
         One fused walk stamps every member with the new block's generation
         and appends the new block to its membership array; the take pass's
-        refcount Counter plus this block's own entry is frozen as the free
+        refcount arrays plus this block's own entry are frozen as the free
         plan for the eventual ``free``/``_reconcile``, and the segments
         (with their generation stamps) as the reusable frozen slices for
         the next cycle. Recycles a destroyed shell when one is available;
@@ -1664,7 +1361,6 @@ class GMLakeAllocator:
         s.sid = sid
         s.size = total_size
         s.n_members = n_members
-        s.active_members = n_members
         s.gen = gen
         s.held = True
         s.va = 0
@@ -1674,50 +1370,25 @@ class GMLakeAllocator:
         s._refs_mark = 0
         s._chunks = None
         s._extents = None
+        core = self._vec_core
+        slot = core.acquire_sb(s)
+        s.slot = slot
+        core.sb_active[slot] = n_members
         plan_list: List[Tuple[_Seg, int]] = []
-        if self.vectorized:
-            core = self._vec_core
-            slot = core.acquire_sb(s)
-            s.slot = slot
-            core.sb_active[slot] = n_members
-            appends = 0
-            for seg in plan.values():
-                seg.owner = s
-                plan_list.append((seg, seg.gen))
-                if seg.ref_sids is not None:
-                    # owner append: every member of this slice gains one
-                    # edge to the new block — the aggregate extends by one
-                    # (slot, len(entries)) entry (the slot is fresh, so
-                    # uniqueness holds). Array extension per append is the
-                    # hottest numpy cost in the whole cycle, so the entry
-                    # goes on a plain list folded into the arrays at the
-                    # next read (``_seg_refs``); the raw CSR would need
-                    # per-member interleaving, so it is dropped instead
-                    extra = seg.ref_extra
-                    if extra is None:
-                        seg.ref_extra = [(slot, len(seg.entries))]
-                    else:
-                        extra.append((slot, len(seg.entries)))
-                    seg.edge_sid = None
-                    seg.edge_ptr = None
-                    appends += 1
-            if appends:
-                core.counters["seg_cache_appends"] += appends
-            sids, counts = refs
-            n = sids.size
-            rs = np.empty(n + 1, dtype=np.int64)
-            rc = np.empty(n + 1, dtype=np.int64)
-            rs[:n] = sids
-            rc[:n] = counts
-            rs[n] = slot
-            rc[n] = n_members
-            s._refs = (rs, rc)
-        else:
-            for seg in plan.values():
-                seg.owner = s
-                plan_list.append((seg, seg.gen))
-            refs[s] = n_members
-            s._refs = refs
+        for seg in plan.values():
+            seg.owner = s
+            plan_list.append((seg, seg.gen))
+        # the plan's refs gain this block's own entry (the slot is fresh,
+        # so slot ids stay unique)
+        sids, counts = refs
+        n = sids.size
+        rs = np.empty(n + 1, dtype=np.int64)
+        rc = np.empty(n + 1, dtype=np.int64)
+        rs[:n] = sids
+        rc[:n] = counts
+        rs[n] = slot
+        rc[n] = n_members
+        s._refs = (rs, rc)
         for p in members:
             p.holder = s
             p.holder_gen = gen
@@ -1733,7 +1404,7 @@ class GMLakeAllocator:
         """Paper's StitchFree: LRU-evict inactive sBlocks past the VA budget.
 
         O(evicted * (log heap + members)); callers guarantee pending frees
-        are reconciled before eviction runs (so ``active_members`` is exact).
+        are reconciled before eviction runs (so ``sb_active`` is exact).
         """
         if self._sblock_va_bytes <= self.sblock_va_budget:
             return
@@ -1758,9 +1429,9 @@ class GMLakeAllocator:
         sBlock cannot share a member with a *held* one (the shared member
         would make it active) — so no held block's free plan can reference
         this block, and the membership drop is a pure discard sweep, run as
-        one C-level map. Pooled frozen segments cache membership counts;
-        the dead block is appended to the dead-block log and purged from
-        each cached plan's Counter lazily, right before it is next trusted
+        one C-level map. Cached plans freeze membership counts; the dead
+        block's slot goes dead in ``sb_alive`` and is masked out of each
+        cached plan lazily, right before it is next trusted
         (``_purge_refs``). Stale ``holder`` pointers at this block are left
         in place: the generation test reads them as inactive forever (the
         block's gen was bumped at its final free and only grows, even
@@ -1777,13 +1448,12 @@ class GMLakeAllocator:
             map(list.remove, map(_get_sb_refs, members), repeat(s)),
             maxlen=0,
         )
-        if self.vectorized:
-            # dead in sb_alive immediately (purge masks see it); the slot
-            # itself is quarantined until the next dead-log compaction, when
-            # no cached array can name it anymore
-            self._vec_core.release_sb(s.slot)
-        self._dead_refs.append(s)
-        if len(self._dead_refs) > self.DEAD_LOG_LIMIT:
+        # dead in sb_alive immediately (purge masks see it); the slot itself
+        # is quarantined until the next dead-log compaction, when no cached
+        # plan can name it anymore
+        core = self._vec_core
+        core.release_sb(s.slot)
+        if core.quarantined > self.DEAD_LOG_LIMIT:
             self._compact_dead_log()
         if self._deferred_unmap:
             # stream-ordered reclamation: the physical unmap leaves the
@@ -1802,52 +1472,27 @@ class GMLakeAllocator:
             shells.append(s)
 
     def _compact_dead_log(self) -> None:
-        """Reset the destroyed-block log so memory stays O(live), not
-        O(destroys).
+        """Recycle the quarantined (destroyed) sBlock slots so memory stays
+        O(live), not O(destroys).
 
-        The log exists only so *cached* (inactive, reconciled) plans can
-        replay destroys into their frozen Counters before the S1 fast path
-        trusts them. Dropping every inactive block's cached plan makes the
-        whole log dead weight: held/pending plans never contain dead
-        entries (their referencing blocks are active, hence undestroyable)
-        and get a fresh ``_refs_mark`` at their next reconcile, so the log
-        can be cleared outright. Cost: O(live sBlocks), amortized over the
-        4096 destroys that filled the log; the only effect on behaviour is
-        that the next re-hold of an affected block takes the slow path
+        Quarantine exists only so *cached* (inactive, reconciled) plans can
+        mask destroyed slots out of their frozen refcount arrays before the
+        S1 fast path trusts them. Dropping every inactive block's cached
+        plan makes the quarantine dead weight: held/pending plans never
+        name dead slots (their referencing blocks are active, hence
+        undestroyable) and get a fresh ``_refs_mark`` at their next
+        reconcile, so every quarantined slot can be recycled outright.
+        Cost: O(live sBlocks), amortized over the ``DEAD_LOG_LIMIT``
+        destroys that filled the quarantine; the only effect on behaviour
+        is that the next re-hold of an affected block takes the slow path
         once — which rebuilds the cache.
         """
         pending = self._pending_frees
-        if self.vectorized:
-            # Quarantined slots are about to be recycled, so every cached
-            # segment array that could still name one must go: pooled
-            # frozen segments, plus the plan segments of held/pending
-            # blocks (their plan-level refs are safe — a held plan's
-            # referencing blocks are active, hence undestroyable — but a
-            # seg cache may predate the hold). Dropping a cache only costs
-            # a rebuild on its next use.
-            for pool in (self._inactive_p.main, self._inactive_p.sub):
-                for segs in pool._segs.values():
-                    for seg in segs:
-                        seg.ref_sids = None
-                        seg.ref_counts = None
-                        seg.edge_sid = None
-                        seg.edge_ptr = None
-                        seg.ref_extra = None
-            for s in self._sblocks.values():
-                if s._plan is not None:
-                    for seg, _g in s._plan:
-                        seg.ref_sids = None
-                        seg.ref_counts = None
-                        seg.edge_sid = None
-                        seg.edge_ptr = None
-                        seg.ref_extra = None
         for s in self._sblocks.values():
             if s._plan is not None and not s.held and s not in pending:
                 s._plan = None
                 s._refs = None
-        self._dead_refs.clear()
-        if self.vectorized:
-            self._vec_core.compact_sb()
+        self._vec_core.compact_sb()
 
     def _compact_lru_heap(self) -> None:
         self._inactive_s.sweep()  # iteration must see only truly-inactive
@@ -1894,12 +1539,12 @@ class GMLakeAllocator:
 
     def _take_stitch_candidates(
         self, bsize: int, include_sub: bool
-    ) -> Tuple[Dict[int, _Seg], int, Dict['SBlock', int], List[PBlock]]:
+    ) -> Tuple[Dict[int, _Seg], int, tuple, List[PBlock]]:
         """Remove and return the S3 candidate set, largest blocks first.
 
         Walks pool buckets largest-size-first, returning the candidates as
         per-size segments (``plan``) plus the aggregated membership
-        refcount Counter and the member count. A bucket consumed whole
+        refcount arrays and the member list. A bucket consumed whole
         never needs sorting at all (blocks of one size are interchangeable
         for everything the digests pin — only the intra-stitch chunk layout
         differs, which nothing downstream reads); when the whole bucket is
@@ -1921,7 +1566,6 @@ class GMLakeAllocator:
         pools = (pool_main, self._inactive_p.sub) if include_sub else (pool_main,)
         plan: Dict[int, _Seg] = {}
         hotspots = self.hotspots
-        vec = self.vectorized
         total = 0
         split_last: Optional[PBlock] = None
         keep = 0
@@ -2028,64 +1672,25 @@ class GMLakeAllocator:
                 plan[a.size] = _Seg(a.size, [entry])
             else:
                 seg.entries.append(entry)
-                # the slice gained a member the caches never saw (the half
-                # inherits its parent's membership). The per-edge CSR goes
-                # stale either way, but the aggregate is patched in place
-                # when present — each inherited referencing block counts
-                # the half exactly once — instead of forcing a full
-                # rebuild of a slice this very take just merged.
-                seg.edge_sid = None
-                seg.edge_ptr = None
-                sids = seg.ref_sids
-                if sids is None:
-                    seg.ref_counts = None
-                    seg.ref_extra = None
-                elif a.sb_refs:
-                    nh = len(a.sb_refs)
-                    half_s = np.fromiter(
-                        map(_get_slot, a.sb_refs), np.int64, count=nh
-                    )
-                    seg.ref_sids, seg.ref_counts = _merge_id_parts(
-                        [sids, half_s],
-                        [seg.ref_counts, np.ones(nh, dtype=np.int64)],
-                    )
             total += keep
         # flatten the candidate set once — the take, the refcount pass and
-        # the stitch all share this list. Both cores count the flat
-        # membership edges in ONE aggregated C-level pass (the measured
-        # optimum at serving scale — see _count_segs_refs); the vectorized
-        # core then carries the result as a (sids, counts) array pair. The
-        # counts become the new block's frozen free-plan refs, applied as
-        # one batch.
+        # the stitch all share this list. The flat membership edges are
+        # counted in ONE aggregated C-level pass (the measured optimum at
+        # serving scale — see _count_segs_refs) into a (sids, counts) array
+        # pair, which becomes the new block's frozen free-plan refs,
+        # applied as one batch.
         members: List[PBlock] = []
         ma = members.append
         for seg in plan.values():
             for e in seg.entries:
                 ma(e[1])
-        if vec:
-            refs = self._count_segs_refs(list(plan.values()))
-        else:
-            refs = self._count_take_refs(plan.values())
+        refs = self._count_segs_refs(list(plan.values()))
         self._apply_activation(refs)
         return plan, total, refs, members
 
-    def _count_take_refs(self, plan_segs) -> Dict["SBlock", int]:
-        """The take tail's membership count pass, object path: flatten the
-        candidate set's pBlock→sBlock edges once and count them in ONE
-        C-level pass. Isolated as its own frame so the profile harness
-        can compare it like-for-like against the vectorized merge
-        (``_count_segs_refs`` + ``_merge_recount_cache``)."""
-        edges: List[SBlock] = []
-        for seg in plan_segs:
-            for e in seg.entries:
-                edges += e[1].sb_refs
-        refs: Dict[SBlock, int] = {}
-        _count_elements(refs, edges)
-        return refs
-
     def _take_all(
         self, include_sub: bool, activate: bool = True
-    ) -> Tuple[Dict[int, _Seg], int, Dict['SBlock', int], List[PBlock]]:
+    ) -> Tuple[Dict[int, _Seg], int, tuple, List[PBlock]]:
         """Drain the stitchable pool(s) for S4.
 
         ``activate`` applies the handout-side membership refcount bump —
@@ -2098,17 +1703,13 @@ class GMLakeAllocator:
         pool_main = self._inactive_p.main
         pools = (pool_main, self._inactive_p.sub) if include_sub else (pool_main,)
         plan: Dict[int, _Seg] = {}
-        refs: Dict[SBlock, int] = {}
         members: List[PBlock] = []
         total = 0
-        vec = self.vectorized
         for pool in pools:
             for size in reversed(pool._sizes):
                 bucket = pool._settled(size)
                 total += size * len(bucket)
                 members += [e[1] for e in bucket]
-                if not vec:
-                    _count_entry_sids(refs, bucket)
                 # main/sub sizes are disjoint partitions: no key collisions
                 plan[size] = _Seg(size, bucket)
             pool._buckets = {}
@@ -2117,8 +1718,7 @@ class GMLakeAllocator:
             pool._sizes.clear()
             pool._count = 0
             pool.bytes = 0
-        if vec:
-            refs = self._count_segs_refs(list(plan.values()))
+        refs = self._count_segs_refs(list(plan.values()))
         if activate:
             self._apply_activation(refs)
         return plan, total, refs, members
@@ -2220,14 +1820,6 @@ class GMLakeAllocator:
             plan[new_p.size] = _Seg(new_p.size, [entry])
         else:
             seg.entries.append(entry)
-            # new_p has no referencing sBlocks yet, so the aggregated counts
-            # would stay exact — but the raw CSR gains a member row, and a
-            # half-valid cache is a trap: drop it all, S4 is rare
-            seg.ref_sids = None
-            seg.ref_counts = None
-            seg.edge_sid = None
-            seg.edge_ptr = None
-            seg.ref_extra = None
         members.append(new_p)
         # new_p is fresh: its sb_refs are empty, no refs contribution
         return self._stitch_plan(plan, total + new_p.size, refs, members)
@@ -2345,8 +1937,7 @@ class GMLakeAllocator:
                 self._inactive_p.add(p)
                 continue
             del self._pblocks[p.pid]
-            if self.vectorized:
-                self._vec_core.release_pb(p.slot)
+            self._vec_core.release_pb(p.slot)
             n = len(p.chunks)
             self.device.cu_mem_unmap(n)
             self.device.cu_mem_address_free()
@@ -2390,10 +1981,8 @@ class GMLakeAllocator:
     # debug / test support
     # ------------------------------------------------------------------
     def _refs_as_dict(self, refs) -> Dict[SBlock, int]:
-        """Normalize a plan's frozen refcounts (Counter or array pair) to a
-        plain ``{SBlock: count}`` dict for invariant comparison."""
-        if not self.vectorized:
-            return dict(refs)
+        """A plan's frozen ``(sids, counts)`` refcounts as a plain
+        ``{SBlock: count}`` dict for invariant comparison."""
         by_slot = self._vec_core.sb_by_slot
         sids, counts = refs
         return {
@@ -2401,100 +1990,13 @@ class GMLakeAllocator:
             for slot, c in zip(sids.tolist(), counts.tolist())
         }
 
-    def _check_vec_invariants(self) -> None:
-        """Slot-table and cached-array invariants of the vectorized core."""
-        core = self._vec_core
-        # live sBlocks <-> slots: unique, alive, resolvable, exact counts
-        slots_seen = set()
-        for s in self._sblocks.values():
-            slot = s.slot
-            assert 0 <= slot < len(core.sb_by_slot), "sBlock slot out of range"
-            assert slot not in slots_seen, "duplicate sBlock slot"
-            slots_seen.add(slot)
-            assert core.sb_alive[slot], "live sBlock with dead slot"
-            assert core.sb_by_slot[slot] is s, "slot table points elsewhere"
-            truth = sum(1 for p in s.members() if p.active)
-            assert int(core.sb_active[slot]) == truth, "slot activity drifted"
-        alive_slots = set(np.flatnonzero(core.sb_alive).tolist())
-        assert alive_slots == slots_seen, "sb_alive disagrees with registry"
-        # free / quarantined slots are disjoint from live and from each other
-        free = set(core._sb_free)
-        quarantined = set(core._sb_quarantine)
-        assert len(free) == len(core._sb_free), "duplicate free slot"
-        assert not (free & slots_seen), "live slot on the free list"
-        assert not (quarantined & slots_seen), "live slot quarantined"
-        assert not (free & quarantined), "slot both free and quarantined"
-        # pBlock slots: dense, unique among live blocks
-        pb_slots = [p.slot for p in self._pblocks.values()]
-        assert all(sl >= 0 for sl in pb_slots), "unslotted live pBlock"
-        assert len(set(pb_slots)) == len(pb_slots), "duplicate pBlock slot"
-        # cached segment arrays: after an aliveness purge, the aggregate
-        # must equal a fresh count of the slice's membership edges, and a
-        # surviving CSR must aggregate to exactly that
-        pool_segs = [
-            seg
-            for pool in (self._inactive_p.main, self._inactive_p.sub)
-            for segs in pool._segs.values()
-            for seg in segs
-        ]
-        # gen-stale plan segments (slice consumed by a later take) keep
-        # whatever cache they had when the plan froze — harmless, because
-        # the gen check rejects the plan before any cache read. Only
-        # gen-valid segments must stay exact.
-        plan_segs = [
-            seg
-            for s in self._sblocks.values()
-            if s._plan is not None
-            for seg, _g in s._plan
-            if seg.gen == _g
-        ]
-        alive = core.sb_alive
-        for seg in pool_segs + plan_segs:
-            if seg.ref_sids is None:
-                # a CSR never outlives its aggregate (every invalidation
-                # site drops the pair together)
-                assert seg.edge_sid is None and seg.edge_ptr is None
-            # builds the aggregate on miss, folds buffered appends on hit
-            # — the checker is what keeps the cache/fold/CSR machinery
-            # exercised now that the hot take path counts edges directly
-            sids, counts = self._seg_refs(seg)
-            fresh: Dict[int, int] = {}
-            for _pid, p in seg.entries:
-                _count_elements(fresh, map(_get_slot, p.sb_refs))
-            # cached arrays may still name destroyed slots (destroys never
-            # walk the caches); mask-compact here — sound at any time, since
-            # a dead slot stays quarantined until ``_compact_dead_log``
-            # drops every cache — so the invariant is: the *alive* subset
-            # must equal a fresh count
-            if sids.size:
-                keep = alive[sids]
-                if not keep.all():
-                    sids = sids[keep]
-                    counts = counts[keep]
-                    seg.ref_sids = sids
-                    seg.ref_counts = counts
-                    core.counters["ref_purges"] += 1
-            cached = dict(zip(sids.tolist(), counts.tolist()))
-            assert cached == fresh, "cached segment refcounts drifted"
-            # materialize a *fresh* CSR (a cached one may predate destroys
-            # — `sb_refs` scrubbing changes the edge list under it) and
-            # cross-validate its layout and aggregation
-            seg.edge_sid = None
-            seg.edge_ptr = None
-            edge_sid, ptr = self._seg_edges(seg)
-            assert len(ptr) == len(seg.entries) + 1
-            assert ptr[0] == 0 and ptr[-1] == len(edge_sid)
-            csr: Dict[int, int] = {}
-            _count_elements(csr, edge_sid.tolist())
-            assert csr == fresh, "CSR edges disagree with aggregate"
-
     def check_invariants(self) -> None:
         """Validate every structural invariant (test/debug only; O(blocks)).
 
-        Verifies the round-4 frozen-segment invariants first (a frozen
-        segment's cached Counter must equal a fresh count of its members'
-        sid arrays — the property that makes plan-identity reuse
-        bit-identical), then reconciles pending frees and checks the
+        Verifies the round-4 frozen-plan invariants first (a plan's cached
+        refcounts must equal a fresh count of its members' ``sb_refs`` —
+        the property that makes plan-identity reuse bit-identical), then
+        reconciles pending frees and checks the slot tables and the
         classic pool/refcount/LRU invariants. Reconciliation timing is
         unobservable to callers, so this never perturbs replay behaviour
         (the settle it forces kills frozen segments, which only disables
@@ -2519,8 +2021,8 @@ class GMLakeAllocator:
                     "frozen plan refs drifted"
                 )
         # inactive cached plans: when every generation still matches (the
-        # S1 fast path would fire), the cached Counter must equal a fresh
-        # count after the dead-log replay — the plan-identity soundness
+        # S1 fast path would fire), the cached refcounts must equal a fresh
+        # count after the aliveness purge — the plan-identity soundness
         # property itself
         for s in self._sblocks.values():
             plan = s._plan
@@ -2549,8 +2051,29 @@ class GMLakeAllocator:
 
         self._reconcile()
         self._inactive_s.sweep()  # drop lazily-delisted (stale) entries
-        if self.vectorized:
-            self._check_vec_invariants()
+        # slot tables: live sBlocks <-> slots unique, alive, resolvable
+        core = self._vec_core
+        slots_seen = set()
+        for s in self._sblocks.values():
+            slot = s.slot
+            assert 0 <= slot < len(core.sb_by_slot), "sBlock slot out of range"
+            assert slot not in slots_seen, "duplicate sBlock slot"
+            slots_seen.add(slot)
+            assert core.sb_alive[slot], "live sBlock with dead slot"
+            assert core.sb_by_slot[slot] is s, "slot table points elsewhere"
+        alive_slots = set(np.flatnonzero(core.sb_alive).tolist())
+        assert alive_slots == slots_seen, "sb_alive disagrees with registry"
+        # free / quarantined slots are disjoint from live and from each other
+        free = set(core._sb_free)
+        quarantined = set(core._sb_quarantine)
+        assert len(free) == len(core._sb_free), "duplicate free slot"
+        assert not (free & slots_seen), "live slot on the free list"
+        assert not (quarantined & slots_seen), "live slot quarantined"
+        assert not (free & quarantined), "slot both free and quarantined"
+        # pBlock slots: dense, unique among live blocks
+        pb_slots = [p.slot for p in self._pblocks.values()]
+        assert all(sl >= 0 for sl in pb_slots), "unslotted live pBlock"
+        assert len(set(pb_slots)) == len(pb_slots), "duplicate pBlock slot"
         seen_chunks: Dict[int, int] = {}
         inactive_ids = {p.pid for p in self._inactive_p}
         for p in self._pblocks.values():
@@ -2567,7 +2090,9 @@ class GMLakeAllocator:
             assert s.size == sum(p.size for p in members)
             assert s.n_members == len(members)
             active_n = self._active_of(s)
-            assert active_n == sum(1 for p in members if p.active)
+            assert active_n == sum(1 for p in members if p.active), (
+                "slot activity drifted"
+            )
             assert s.active == (active_n > 0)
             if s.held:  # held: every member stamped with the current gen
                 assert all(
@@ -2592,3 +2117,4 @@ class GMLakeAllocator:
             for p in pool:
                 assert (p.size < self.frag_limit) == below
         assert self._inactive_s.bytes == sum(s.size for s in self._inactive_s)
+

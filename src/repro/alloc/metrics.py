@@ -30,9 +30,9 @@ class AllocatorStats:
     #: hit tallies: seg_reuse / hold_fast / shell_reuse). Never part of the
     #: golden digests — purely observability for the profile harness.
     counters: Optional[dict] = None
-    #: GMLake round-5 vectorized-core counters (enabled / numpy_fallback /
-    #: seg_cache_builds / ref_purges / ...). Same observability-only
-    #: contract as ``counters``; surfaced via ``ReplayResult.vec_counters``.
+    #: GMLake round-5 slot-table counters (ref_purges / slot_grows /
+    #: dead_compactions). Same observability-only contract as
+    #: ``counters``; surfaced via ``ReplayResult.vec_counters``.
     vec_counters: Optional[dict] = None
 
     def __post_init__(self) -> None:
@@ -143,9 +143,9 @@ class ReplayResult:
     #: ``AllocatorEventLog.summary()`` when the backend logged recovery /
     #: reclamation events during the replay; None on a quiet run
     recovery: Optional[dict] = None
-    #: snapshot of the backend's vectorized-core counters (GMLake round 5:
-    #: enabled / numpy_fallback / seg_cache_builds / ref_purges / ...);
-    #: None for backends without a vectorized core
+    #: snapshot of the backend's slot-table counters (GMLake round 5:
+    #: ref_purges / slot_grows / dead_compactions); None for backends
+    #: without slot tables
     vec_counters: Optional[dict] = None
     #: planned-vs-spilled routing tallies of the hybrid backend
     #: (planned_allocs/planned_bytes/spilled_allocs/spilled_bytes);

@@ -552,7 +552,7 @@ def _resolve_allocator(
 
 def _replay_result(allocator, wall, oom, oom_at) -> ReplayResult:
     event_log = getattr(allocator, "event_log", None)
-    # vectorized-core observability (GMLake round 5), surfaced exactly like
+    # slot-table observability (GMLake round 5), surfaced exactly like
     # recovery summaries: snapshot the backend's counter dict when present
     vec_counters = getattr(allocator, "vec_counters", None)
     hybrid_counters = getattr(allocator, "hybrid_counters", None)
@@ -606,8 +606,8 @@ def replay(
     ``AllocatorOOM`` only when a backend's recovery ladder is exhausted.
 
     Extra keyword arguments are forwarded to the backend constructor when
-    ``allocator`` is a registry key (e.g. ``vectorized=False`` or
-    ``va_budget="tight"`` for gmlake's array-core / StitchFree knobs).
+    ``allocator`` is a registry key (e.g. ``va_budget="tight"`` for
+    gmlake's StitchFree VA budget).
     """
     allocator = _resolve_allocator(
         allocator, trace, capacity_bytes, fault_schedule=fault_schedule,

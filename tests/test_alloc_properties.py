@@ -11,20 +11,14 @@ every operation and at drain:
     escaping a backend is a bug (the fault layer depends on this);
   * draining every live allocation leaves active at zero, and after
     ``release_cached`` + deferred-unmap drain the device agrees with the
-    backend about what is still reserved;
-  * gmlake's plan-identity fast paths are *frozen policy*: the same
-    program replayed with ``plan_identity=False`` must produce identical
-    S1..S5 state counts and peaks;
-  * gmlake's round-5 vectorized core is likewise frozen policy: the
-    object-path escape hatch (``vectorized=False``) gets its own fuzz
-    class, and a parity property pins digest identity between the cores.
+    backend about what is still reserved.
 
-Runs through ``_hypothesis_compat``: with hypothesis installed these are
-real property tests; without it the deterministic fallback executes the
-same number of seeded examples, so the layer never silently skips.
-200 examples per fuzz class (6 x 200 = 1200 programs + 2 x 100 parity
-pairs) keep within the suite's wall budget because programs are pure
-host-side metadata churn.
+Each contract is one module-level ``@given`` property parametrised over
+the registry's backends, so every backend runs its own examples (one
+property body shared by several test classes trips hypothesis's
+``differing_executors`` health check). 200 random and 100 faulted
+examples per backend keep within the suite's wall budget because
+programs are pure host-side metadata churn.
 """
 
 import random
@@ -42,15 +36,18 @@ from repro.alloc import (
     registry,
 )
 from repro.alloc.chunks import DeviceOOM
-from repro.alloc.gmlake import GMLakeAllocator
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 CAPACITY = 256 * MB
 N_OPS = 60
 #: op mix: weights for (alloc_small, alloc_large, free, release, shrink)
 _OP_WEIGHTS = (34, 14, 38, 10, 4)
 _OPS = ("alloc_small", "alloc_large", "free", "release", "shrink")
+
+#: every registered backend; the coverage tests below fail when a new
+#: registration is missing here
+BACKENDS = ("caching", "native", "gmlake", "stalloc", "ellm", "hybrid")
 
 
 def _program(seed: int):
@@ -84,86 +81,46 @@ def _drain(alloc, live, device):
     assert device.used_bytes == alloc.reserved_bytes
 
 
-class _Fuzz:
-    """One @given body per backend; subclasses pin the backend name so
-    pytest reports (and the fallback seeds) stay per-backend stable.
-    ``kwargs`` lets a subclass fuzz a non-default configuration of an
-    already-registered backend (round 5: gmlake's object-path core)."""
-
-    backend = None
-    kwargs = {}
-
-    @given(st.integers(min_value=0, max_value=2**31 - 1))
-    @settings(max_examples=200, deadline=None)
-    def test_random_interleaving_upholds_contract(self, seed):
-        ops = _program(seed)
-        device = VMMDevice(CAPACITY)
-        alloc = registry.create(self.backend, device, **self.kwargs)
-        # run with frees actually applied: re-execute with a live list
-        live = []
-        n_ok = 0
-        for i, (op, arg) in enumerate(ops):
-            if op == "alloc":
-                try:
-                    live.append(alloc.malloc(arg))
-                    n_ok += 1
-                except AllocatorOOM:
-                    pass
-                except DeviceOOM as e:
-                    raise AssertionError(
-                        f"raw DeviceOOM escaped {alloc.name}: {e}"
-                    ) from e
-            elif op == "free" and live:
-                alloc.free(live.pop(int(arg * len(live)) % len(live)))
-            elif op == "shrink":
-                device.shrink(arg)
-            elif op == "release":
-                alloc.release_cached()
-            assert alloc.stats.active_bytes <= alloc.reserved_bytes, (
-                f"{alloc.name}: active exceeds reserved after op {i} ({op})"
-            )
-            if i % 7 == 0:
-                alloc.check_invariants()
-        _drain(alloc, live, device)
+def _run_program(backend: str, device, seed: int) -> None:
+    """Run ``seed``'s program on a fresh ``backend`` over ``device``,
+    checking the contract after every op and at drain."""
+    ops = _program(seed)
+    alloc = registry.create(backend, device)
+    live = []
+    for i, (op, arg) in enumerate(ops):
+        if op == "alloc":
+            try:
+                live.append(alloc.malloc(arg))
+            except AllocatorOOM:
+                pass
+            except DeviceOOM as e:
+                raise AssertionError(
+                    f"raw DeviceOOM escaped {alloc.name}: {e}"
+                ) from e
+        elif op == "free" and live:
+            alloc.free(live.pop(int(arg * len(live)) % len(live)))
+        elif op == "shrink":
+            device.shrink(arg)
+        elif op == "release":
+            alloc.release_cached()
+        assert alloc.stats.active_bytes <= alloc.reserved_bytes, (
+            f"{alloc.name}: active exceeds reserved after op {i} ({op})"
+        )
+        if i % 7 == 0:
+            alloc.check_invariants()
+    _drain(alloc, live, device)
 
 
-class TestCachingFuzz(_Fuzz):
-    backend = "caching"
-
-
-class TestNativeFuzz(_Fuzz):
-    backend = "native"
-
-
-class TestGMLakeFuzz(_Fuzz):
-    backend = "gmlake"
-
-
-class TestGMLakeObjectPathFuzz(_Fuzz):
-    """The ``vectorized=False`` escape hatch is a supported long-term mode
-    (it is the A/B reference and the numpy-free fallback), so it gets the
-    same fuzz coverage as the default vectorized core."""
-
-    backend = "gmlake"
-    kwargs = {"vectorized": False}
-
-
-class TestSTAllocFuzz(_Fuzz):
-    backend = "stalloc"
-
-
-class TestELLMFuzz(_Fuzz):
-    backend = "ellm"
-
-
-class TestHybridFuzz(_Fuzz):
-    backend = "hybrid"
+@pytest.mark.parametrize("backend", BACKENDS)
+@given(st.integers(min_value=0, max_value=2**31 - 1))
+@settings(max_examples=200, deadline=None)
+def test_random_interleaving_upholds_contract(backend, seed):
+    _run_program(backend, VMMDevice(CAPACITY), seed)
 
 
 def test_every_backend_is_fuzzed():
     """A new backend registration must join the property layer."""
-    fuzzed = {c.backend for c in _Fuzz.__subclasses__()}
-    assert fuzzed == set(registry.names())
+    assert set(BACKENDS) == set(registry.names())
 
 
 # ---------------------------------------------------------------------------
@@ -193,12 +150,11 @@ def _fault_schedule(seed: int) -> FaultSchedule:
     )
 
 
-class _FaultFuzz:
-    """The ``_Fuzz`` programs re-run over a fault-injected device.
-
-    Deliberately NOT a ``_Fuzz`` subclass: the fault family derives its
-    own schedule per seed and has its own coverage gate below, while
-    ``test_every_backend_is_fuzzed`` keys off ``_Fuzz.__subclasses__()``.
+@pytest.mark.parametrize("backend", BACKENDS)
+@given(st.integers(min_value=0, max_value=2**31 - 1))
+@settings(max_examples=100, deadline=None)
+def test_faulted_interleaving_upholds_contract(backend, seed):
+    """The random programs re-run over a fault-injected device.
 
     Mid-fault ladder contract, asserted after *every* op while windows
     are live: a raw ``DeviceOOM`` (transient or not) never escapes a
@@ -207,117 +163,10 @@ class _FaultFuzz:
     release-side faults (frees are fire-and-forget; a release fault must
     stall, never leak).
     """
-
-    backend = None
-    kwargs = {}
-
-    @given(st.integers(min_value=0, max_value=2**31 - 1))
-    @settings(max_examples=100, deadline=None)
-    def test_faulted_interleaving_upholds_contract(self, seed):
-        ops = _program(seed)
-        device = FaultInjector(VMMDevice(CAPACITY), _fault_schedule(seed))
-        alloc = registry.create(self.backend, device, **self.kwargs)
-        live = []
-        for i, (op, arg) in enumerate(ops):
-            if op == "alloc":
-                try:
-                    live.append(alloc.malloc(arg))
-                except AllocatorOOM:
-                    pass
-                except DeviceOOM as e:
-                    raise AssertionError(
-                        f"raw DeviceOOM escaped {alloc.name} mid-fault: {e}"
-                    ) from e
-            elif op == "free" and live:
-                alloc.free(live.pop(int(arg * len(live)) % len(live)))
-            elif op == "shrink":
-                device.shrink(arg)
-            elif op == "release":
-                alloc.release_cached()
-            assert alloc.stats.active_bytes <= alloc.reserved_bytes, (
-                f"{alloc.name}: active exceeds reserved after op {i} ({op})"
-            )
-            if i % 7 == 0:
-                alloc.check_invariants()
-        _drain(alloc, live, device)
-
-
-class TestCachingFaultFuzz(_FaultFuzz):
-    backend = "caching"
-
-
-class TestNativeFaultFuzz(_FaultFuzz):
-    backend = "native"
-
-
-class TestGMLakeFaultFuzz(_FaultFuzz):
-    backend = "gmlake"
-
-
-class TestSTAllocFaultFuzz(_FaultFuzz):
-    backend = "stalloc"
-
-
-class TestELLMFaultFuzz(_FaultFuzz):
-    backend = "ellm"
-
-
-class TestHybridFaultFuzz(_FaultFuzz):
-    backend = "hybrid"
+    device = FaultInjector(VMMDevice(CAPACITY), _fault_schedule(seed))
+    _run_program(backend, device, seed)
 
 
 def test_every_backend_is_fault_fuzzed():
     """A new backend registration must join the fault property layer."""
-    fuzzed = {c.backend for c in _FaultFuzz.__subclasses__()}
-    assert fuzzed == set(registry.names())
-
-
-# ---------------------------------------------------------------------------
-# gmlake plan-identity parity: fast paths are frozen policy under fuzzing
-# ---------------------------------------------------------------------------
-
-
-def _gmlake_digest(seed: int, plan_identity: bool = True, **kwargs):
-    ops = _program(seed)
-    device = VMMDevice(CAPACITY)
-    alloc = GMLakeAllocator(device, plan_identity=plan_identity, **kwargs)
-    live = []
-    for op, arg in ops:
-        if op == "alloc":
-            try:
-                live.append(alloc.malloc(arg))
-            except AllocatorOOM:
-                pass
-        elif op == "free" and live:
-            alloc.free(live.pop(int(arg * len(live)) % len(live)))
-        elif op == "shrink":
-            device.shrink(arg)
-        elif op == "release":
-            alloc.release_cached()
-    for a in live:
-        alloc.free(a)
-    return (
-        dict(alloc.state_counts),
-        alloc.stats.peak_active,
-        alloc.stats.peak_reserved,
-        alloc.stats.n_alloc,
-        alloc.stats.n_free,
-    )
-
-
-@given(st.integers(min_value=0, max_value=2**31 - 1))
-@settings(max_examples=100, deadline=None)
-def test_gmlake_plan_identity_parity(seed):
-    """Round-4 fast paths must be invisible: identical state counts and
-    peaks with plan_identity on and off, for any seeded interleaving."""
-    assert _gmlake_digest(seed, True) == _gmlake_digest(seed, False)
-
-
-@given(st.integers(min_value=0, max_value=2**31 - 1))
-@settings(max_examples=100, deadline=None)
-def test_gmlake_vectorized_parity(seed):
-    """Round-5 vectorized core must be invisible: identical state counts
-    and peaks with vectorized on and off, for any seeded interleaving."""
-    assert _gmlake_digest(seed, vectorized=True) == _gmlake_digest(
-        seed, vectorized=False
-    )
+    assert set(BACKENDS) == set(registry.names())

@@ -3,7 +3,7 @@
 import random
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     CHUNK_SIZE,
@@ -140,7 +140,7 @@ def test_small_allocs_use_splitting_pool():
 
 
 def test_stitchfree_lru_eviction():
-    a = make_gmlake(sblock_va_budget=256 * MB)
+    a = make_gmlake(va_budget=256 * MB)
     for sz in (96, 80, 112):
         xs = [a.malloc(16 * MB) for _ in range(sz // 16)]
         for x in xs:

@@ -113,37 +113,3 @@ def test_param_count_sanity():
     for aid, target in approx.items():
         n = ARCHS[aid].full.n_params
         assert 0.5 * target < n < 1.7 * target, f"{aid}: {n:.2e} vs {target:.2e}"
-
-
-def test_property_layer_never_silently_skips():
-    """The suite's property tests must *run* everywhere: either real
-    hypothesis is installed, or the deterministic fallback in
-    ``_hypothesis_compat`` executes seeded examples. Historically the
-    suite carried 5 skips when hypothesis was absent; this pins the
-    burn-down."""
-    import _hypothesis_compat as hc
-
-    if hc.HAVE_HYPOTHESIS:
-        return  # the real engine runs the examples
-
-    ran = []
-
-    @hc.given(hc.st.integers(min_value=0, max_value=10))
-    @hc.settings(max_examples=7, deadline=None)
-    def probe(x):
-        assert 0 <= x <= 10
-        ran.append(x)
-
-    probe()
-    assert len(ran) == 7
-    # counterexamples reproduce: the same decorated test draws the same
-    # example sequence on every run
-    again = []
-
-    @hc.given(hc.st.integers(min_value=0, max_value=10))
-    @hc.settings(max_examples=7, deadline=None)
-    def probe(x):  # noqa: F811 - same name on purpose: same seed stream
-        again.append(x)
-
-    probe()
-    assert again == ran

@@ -432,3 +432,104 @@ def test_reconcile_timing_is_unobservable(trace_key, cadence, traces):
     allocator = GMLakeAllocator(VMMDevice(80 * GB))
     res, _ = replay(trace, allocator, check_invariants_every=cadence)
     assert _digest(res) == GOLDEN[(trace_key, "gmlake", 80)]
+
+
+# ---------------------------------------------------------------------------
+# gmlake digests pinned on the traces and settings that once compared its
+# two refcount cores and its plan-identity fast paths against their
+# force-disabled twin. Recorded on the last tree that carried those paths,
+# where every path replayed to these exact digests; the one core left must
+# keep them at every invariant-check cadence (each check forces a
+# reconcile and a settle mid-trace).
+# ---------------------------------------------------------------------------
+
+# (trace key, va_budget tier) -> digest, including modeled device cost
+CORE_PINS = {
+    ("serve_vicuna_600_s3", "paper"): dict(
+        state_counts={"S1": 11, "S2": 69, "S3": 497, "S4": 23, "S5": 0},
+        peak_active=21386756096, peak_reserved=21533556736,
+        oom=False, oom_at_event=None,
+        n_alloc=600, n_free=600, model_cost=53178.620410156, marks=[],
+    ),
+    ("serve_vicuna_500_s3", "paper"): dict(
+        state_counts={"S1": 10, "S2": 65, "S3": 402, "S4": 23, "S5": 0},
+        peak_active=21386756096, peak_reserved=21533556736,
+        oom=False, oom_at_event=None,
+        n_alloc=500, n_free=500, model_cost=44605.307042969, marks=[],
+    ),
+    ("train_opt1.3b_LR_i4_s1", "paper"): dict(
+        state_counts={"S1": 1507, "S2": 104, "S3": 6, "S4": 136, "S5": 0},
+        peak_active=7056916480, peak_reserved=7082082304,
+        oom=False, oom_at_event=None,
+        n_alloc=2233, n_free=2036, model_cost=3275.18759375,
+        marks=[
+            ("iter0", {"S1": 0, "S2": 0, "S3": 0, "S4": 97, "S5": 0}),
+            ("iter1", {"S1": 369, "S2": 7, "S3": 1, "S4": 134, "S5": 0}),
+            ("iter2", {"S1": 747, "S2": 42, "S3": 2, "S4": 134, "S5": 0}),
+            ("iter3", {"S1": 1128, "S2": 72, "S3": 3, "S4": 136, "S5": 0}),
+            ("end", {"S1": 1507, "S2": 104, "S3": 6, "S4": 136, "S5": 0}),
+        ],
+    ),
+    # every StitchFree policy tier on one trace: the tier changes policy,
+    # so each has its own digest
+    ("serve_vicuna_600_s7", "tight"): dict(
+        state_counts={"S1": 10, "S2": 72, "S3": 496, "S4": 22, "S5": 0},
+        peak_active=22443720704, peak_reserved=22470983680,
+        oom=False, oom_at_event=None,
+        n_alloc=600, n_free=600, model_cost=53137.70778125, marks=[],
+    ),
+    ("serve_vicuna_600_s7", "paper"): dict(
+        state_counts={"S1": 11, "S2": 69, "S3": 498, "S4": 22, "S5": 0},
+        peak_active=22443720704, peak_reserved=22468886528,
+        oom=False, oom_at_event=None,
+        n_alloc=600, n_free=600, model_cost=53024.658851562, marks=[],
+    ),
+    ("serve_vicuna_600_s7", "speed"): dict(
+        state_counts={"S1": 16, "S2": 69, "S3": 493, "S4": 22, "S5": 0},
+        peak_active=22443720704, peak_reserved=22468886528,
+        oom=False, oom_at_event=None,
+        n_alloc=600, n_free=600, model_cost=52745.6513125, marks=[],
+    ),
+}
+
+
+def _core_trace(key):
+    if key == "train_opt1.3b_LR_i4_s1":
+        return training_trace(
+            PAPER_MODELS["opt-1.3b"], "LR", world=4, batch=8, seq=2048,
+            iters=4, seed=1,
+        )
+    n_requests, seed = {
+        "serve_vicuna_600_s3": (600, 3),
+        "serve_vicuna_500_s3": (500, 3),
+        "serve_vicuna_600_s7": (600, 7),
+    }[key]
+    return inference_trace(
+        PAPER_MODELS["vicuna-13b"], n_requests=n_requests, seed=seed
+    )
+
+
+@pytest.mark.parametrize(
+    "trace_key,budget,cadence",
+    [
+        ("serve_vicuna_600_s3", "paper", 0),
+        ("serve_vicuna_600_s3", "paper", 97),
+        ("serve_vicuna_500_s3", "paper", 0),
+        ("serve_vicuna_500_s3", "paper", 7),
+        ("serve_vicuna_500_s3", "paper", 101),
+        ("train_opt1.3b_LR_i4_s1", "paper", 0),
+        ("train_opt1.3b_LR_i4_s1", "paper", 53),
+        ("serve_vicuna_600_s7", "tight", 61),
+        ("serve_vicuna_600_s7", "paper", 61),
+        ("serve_vicuna_600_s7", "speed", 61),
+    ],
+)
+def test_gmlake_core_digest_pinned(trace_key, budget, cadence):
+    allocator = GMLakeAllocator(VMMDevice(80 * GB), va_budget=budget)
+    res, marks = replay(
+        _core_trace(trace_key), allocator, check_invariants_every=cadence
+    )
+    digest = dict(
+        _digest(res), model_cost=round(res.model_cost, 9), marks=marks
+    )
+    assert digest == CORE_PINS[(trace_key, budget)]

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import GB, PAPER_MODELS, run_workload, training_trace
 from repro.core.trace import ALLOC, FREE, inference_trace

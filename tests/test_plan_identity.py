@@ -1,14 +1,14 @@
 """Round-4 plan-identity correctness: frozen plans must be bit-inert.
 
-The round-4 fast paths (frozen plan segments, cached refcount Counters,
-plan-generation stamps, the dead-block log, sBlock shell recycling) are
-pure mechanical sympathy: with ``plan_identity=False`` every consumption
-re-counts membership from the flat arrays and ``_hold_sblock`` always
-walks. These tests pin that the two modes are bit-identical on every
-digest the golden suite tracks, that the fast path actually fires on the
-free-then-retake-at-the-same-size pattern it targets, and that a *stale*
-cached plan — one whose slices were settled, split, cherry-picked, or
-touched by a StitchFree destroy since the freeze — is never re-activated.
+The round-4 fast paths (frozen plan segments, cached plan refcounts,
+plan-generation stamps, the destroyed-slot purge, sBlock shell
+recycling) are pure mechanical sympathy; their replay digests are pinned
+in ``tests/test_golden_equivalence.py``. These tests pin that the fast
+path actually fires on the free-then-retake-at-the-same-size pattern it
+targets, and that a *stale* cached plan — one whose slices were settled,
+split, cherry-picked, or touched by a StitchFree destroy since the
+freeze — is never re-activated wholesale (the slow re-hold runs
+instead), with the invariant checker holding throughout.
 """
 
 import random
@@ -17,111 +17,41 @@ import pytest
 
 from repro.alloc.caching_allocator import AllocatorOOM
 from repro.alloc.chunks import CHUNK_SIZE, ChunkRun, VMMDevice
-from repro.alloc.gmlake import GMLakeAllocator, SBlock
-from repro.core import GB, MB, PAPER_MODELS, inference_trace, replay, training_trace
+from repro.alloc.gmlake import GMLakeAllocator
+from repro.core import GB, MB
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 # ---------------------------------------------------------------------------
 # helpers
 # ---------------------------------------------------------------------------
 
 
-def _digest(a: GMLakeAllocator) -> dict:
-    return dict(
-        state_counts=dict(a.state_counts),
-        active=a.stats.active_bytes,
-        reserved=a.reserved_bytes,
-        peak_active=a.stats.peak_active,
-        peak_reserved=a.stats.peak_reserved,
-        n_alloc=a.stats.n_alloc,
-        n_free=a.stats.n_free,
-        model_cost=round(a.device.ledger.total, 9),
-    )
-
-
-class _Pair:
-    """Drive two allocators — fast paths on vs force-disabled — in lockstep.
-
-    Every operation must produce identical observable behaviour (sizes,
-    OOM points, state counts, modeled device cost); ``check`` additionally
-    runs both invariant validators and compares full digests.
-    """
+class _Driver:
+    """One allocator behind integer handles: ``malloc`` returns a handle,
+    or -1 when the request surfaces as ``AllocatorOOM``; ``check`` runs
+    the invariant checker."""
 
     def __init__(self, capacity=2 * GB, **kw):
-        self.fast = GMLakeAllocator(VMMDevice(capacity), plan_identity=True, **kw)
-        self.slow = GMLakeAllocator(VMMDevice(capacity), plan_identity=False, **kw)
+        self.alloc = GMLakeAllocator(VMMDevice(capacity), **kw)
         self.live = {}
         self._next = 0
 
     def malloc(self, size) -> int:
-        oom_f = oom_s = False
-        af = as_ = None
         try:
-            af = self.fast.malloc(size)
+            a = self.alloc.malloc(size)
         except AllocatorOOM:
-            oom_f = True
-        try:
-            as_ = self.slow.malloc(size)
-        except AllocatorOOM:
-            oom_s = True
-        assert oom_f == oom_s, "OOM behaviour diverged between modes"
-        if oom_f:
             return -1
-        assert af.block_size == as_.block_size
         tid = self._next
         self._next += 1
-        self.live[tid] = (af, as_)
+        self.live[tid] = a
         return tid
 
     def free(self, tid) -> None:
-        af, as_ = self.live.pop(tid)
-        self.fast.free(af)
-        self.slow.free(as_)
+        self.alloc.free(self.live.pop(tid))
 
     def check(self) -> None:
-        self.fast.check_invariants()
-        self.slow.check_invariants()
-        assert _digest(self.fast) == _digest(self.slow)
-
-
-# ---------------------------------------------------------------------------
-# digest equality with the fast paths force-disabled (golden-style)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("cadence", [0, 7, 101])
-def test_serving_trace_digest_identical_either_mode(cadence):
-    """The stress trace (S3-dominant, destroy churn) must replay to the
-    exact same digest with plan identity on and off, at several invariant
-    cadences (checks force settles, which kill frozen segments mid-run)."""
-    trace = inference_trace(PAPER_MODELS["vicuna-13b"], n_requests=500, seed=3)
-    results = {}
-    for flag in (True, False):
-        allocator = GMLakeAllocator(VMMDevice(80 * GB), plan_identity=flag)
-        res, marks = replay(
-            trace, allocator, check_invariants_every=cadence
-        )
-        results[flag] = (
-            res.state_counts, res.stats.peak_active, res.stats.peak_reserved,
-            res.oom, res.oom_at_event, round(res.model_cost, 9), marks,
-        )
-    assert results[True] == results[False]
-
-
-def test_training_trace_digest_identical_either_mode():
-    trace = training_trace(
-        PAPER_MODELS["opt-1.3b"], "LR", world=4, batch=8, seq=2048, iters=4, seed=1
-    )
-    results = {}
-    for flag in (True, False):
-        allocator = GMLakeAllocator(VMMDevice(80 * GB), plan_identity=flag)
-        res, _ = replay(trace, allocator)
-        results[flag] = (
-            res.state_counts, res.stats.peak_active, res.stats.peak_reserved,
-            round(res.model_cost, 9),
-        )
-    assert results[True] == results[False]
+        self.alloc.check_invariants()
 
 
 # ---------------------------------------------------------------------------
@@ -133,7 +63,7 @@ def test_plan_identity_reactivates_frozen_plan():
     """free -> retake at the same size class is the targeted pattern: after
     the first stitched handout, every further cycle re-activates the cached
     plan wholesale (S1 + hold_fast), with no recount and no walk."""
-    pair = _Pair()
+    pair = _Driver()
     a, b = pair.malloc(256 * MB), pair.malloc(256 * MB)
     pair.free(a)
     pair.free(b)
@@ -141,21 +71,18 @@ def test_plan_identity_reactivates_frozen_plan():
     for _ in range(cycles):
         m = pair.malloc(512 * MB)  # S3 once, then S1 re-holds
         pair.free(m)
-    assert pair.fast.state_counts["S3"] == 1
-    assert pair.fast.state_counts["S1"] == cycles - 1
-    assert pair.fast.hotspots["hold_fast"] == cycles - 1
-    assert pair.fast.hotspots["hold_slow"] == 0
-    # the force-disabled twin made the identical decisions the slow way
-    assert pair.slow.hotspots["hold_fast"] == 0
-    assert pair.slow.state_counts == pair.fast.state_counts
+    assert pair.alloc.state_counts["S3"] == 1
+    assert pair.alloc.state_counts["S1"] == cycles - 1
+    assert pair.alloc.hotspots["hold_fast"] == cycles - 1
+    assert pair.alloc.hotspots["hold_slow"] == 0
     pair.check()
 
 
 def test_invariant_check_settles_and_downgrades_to_slow_path():
     """check_invariants reconciles + settles, which kills frozen segments;
     the next re-hold must notice (generation mismatch) and take the slow
-    path — and still behave identically."""
-    pair = _Pair()
+    path, which rebuilds the plan."""
+    pair = _Driver()
     a, b = pair.malloc(256 * MB), pair.malloc(256 * MB)
     pair.free(a)
     pair.free(b)
@@ -163,12 +90,12 @@ def test_invariant_check_settles_and_downgrades_to_slow_path():
     pair.free(m)
     pair.check()  # settles the pool: the cached plan's slices are broken up
     m = pair.malloc(512 * MB)
-    assert pair.fast.hotspots["hold_fast"] == 0
-    assert pair.fast.hotspots["hold_slow"] >= 1
+    assert pair.alloc.hotspots["hold_fast"] == 0
+    assert pair.alloc.hotspots["hold_slow"] >= 1
     pair.free(m)
     # the slow re-hold rebuilt fresh frozen segments: fast again from here
     m = pair.malloc(512 * MB)
-    assert pair.fast.hotspots["hold_fast"] == 1
+    assert pair.alloc.hotspots["hold_fast"] == 1
     pair.free(m)
     pair.check()
 
@@ -181,9 +108,8 @@ def test_invariant_check_settles_and_downgrades_to_slow_path():
 def test_member_cherry_pick_invalidates_cached_plan():
     """Taking one member of a reconciled plan directly (S1 pBlock exact)
     settles its bucket; when it comes back, the cached plan must NOT be
-    re-activated wholesale (the slice was broken up) — and behaviour must
-    still match the force-disabled twin exactly."""
-    pair = _Pair()
+    re-activated wholesale (the slice was broken up)."""
+    pair = _Driver()
     a, b = pair.malloc(256 * MB), pair.malloc(254 * MB)
     pair.free(a)
     pair.free(b)
@@ -191,13 +117,13 @@ def test_member_cherry_pick_invalidates_cached_plan():
     pair.free(m)
     # cherry-pick one member size out of the pooled plan...
     c = pair.malloc(256 * MB)
-    assert pair.fast.state_counts["S1"] == 1  # exact pBlock hit
+    assert pair.alloc.state_counts["S1"] == 1  # exact pBlock hit
     pair.free(c)
     # ...then retake the stitched size: the plan survived in *content* but
     # its slices were settled/cherry-picked — wholesale reuse is unsound
     m = pair.malloc(510 * MB)
-    assert pair.fast.hotspots["hold_fast"] == 0
-    assert pair.fast.hotspots["hold_slow"] >= 1
+    assert pair.alloc.hotspots["hold_fast"] == 0
+    assert pair.alloc.hotspots["hold_slow"] >= 1
     pair.free(m)
     pair.check()
 
@@ -206,7 +132,7 @@ def test_split_of_pooled_member_invalidates_cached_plan():
     """A split of a pooled plan member (S2 on a larger request than any
     single block) changes the membership; the stale plan must not be
     re-activated."""
-    pair = _Pair()
+    pair = _Driver()
     a, b = pair.malloc(256 * MB), pair.malloc(256 * MB)
     pair.free(a)
     pair.free(b)
@@ -214,37 +140,39 @@ def test_split_of_pooled_member_invalidates_cached_plan():
     pair.free(m)
     # S2: splits one pooled 256 MB member (frag limit is 8 MB)
     c = pair.malloc(100 * MB)
-    assert pair.fast.state_counts["S2"] == 1
+    assert pair.alloc.state_counts["S2"] == 1
     pair.free(c)
     m = pair.malloc(512 * MB)  # S1 on the (now 3-member) stitched block
-    assert pair.fast.hotspots["hold_fast"] == 0
-    assert pair.fast.hotspots["hold_slow"] >= 1
+    assert pair.alloc.hotspots["hold_fast"] == 0
+    assert pair.alloc.hotspots["hold_slow"] >= 1
     pair.free(m)
     pair.check()
 
 
 def test_destroy_purges_cached_plan_refs():
     """StitchFree destroys between a free and a retake: the cached plan's
-    frozen Counter holds a reference to the destroyed block (they shared
-    members) and must be purged via the dead-block log before the plan is
-    re-activated — a frozen plan must never resurrect a destroyed sBlock."""
-    pair = _Pair(capacity=2 * GB, sblock_va_budget=700 * MB)
+    frozen refcounts name the destroyed block's slot (they shared members)
+    and must be purged against the slot table's aliveness before the plan
+    is re-activated — a frozen plan must never resurrect a destroyed
+    sBlock."""
+    pair = _Driver(capacity=2 * GB, va_budget=700 * MB)
     a, b = pair.malloc(256 * MB), pair.malloc(256 * MB)
     pair.free(a)
     pair.free(b)
     m1 = pair.malloc(512 * MB)  # stitch #1 (va 512 MB, under budget)
+    dead_slot = pair.live[m1].block.slot
     pair.free(m1)
     m2 = pair.malloc(510 * MB)  # stitch #2 over the same members (+ split)
     pair.free(m2)  # va > budget -> StitchFree destroys stitch #1
-    assert len(pair.fast._dead_refs) >= 1
+    assert not pair.alloc._vec_core.sb_alive[dead_slot], "stitch #1 survived"
     # retake stitch #2's size: its cached plan is intact (the destroy only
     # removed the dead block from the shared members' refs), so the fast
-    # path fires — after replaying the dead-block log against the Counter
+    # path fires — after masking the dead slot out of the cached refs
     m3 = pair.malloc(510 * MB)
-    assert pair.fast.hotspots["hold_fast"] == 1
-    dead = pair.fast._dead_refs[0]
-    m3_fast, _ = pair.live[m3]
-    assert dead not in m3_fast.block._refs, "destroyed block resurrected"
+    assert pair.alloc.hotspots["hold_fast"] == 1
+    assert pair.alloc.vec_counters["ref_purges"] == 1
+    refs_slots = pair.live[m3].block._refs[0].tolist()
+    assert dead_slot not in refs_slots, "destroyed block resurrected"
     pair.free(m3)
     pair.check()
 
@@ -257,13 +185,13 @@ def test_destroy_purges_cached_plan_refs():
 @settings(deadline=None, max_examples=10)
 @given(st.integers(min_value=0, max_value=10_000))
 def test_randomized_interleaving_is_mode_identical(seed):
-    """Random take/free/split/destroy interleavings: both modes must agree
-    on every digest at every step, and both invariant validators must hold
-    at random points (which also randomizes settle/reconcile timing)."""
+    """Random take/free/split/destroy interleavings: an out-of-memory
+    request surfaces as ``AllocatorOOM``, and the invariant checker holds
+    at sampled points (which also varies settle/reconcile timing)."""
     rng = random.Random(seed)
     # small device + tight VA budget: forces stitching, splits, StitchFree
     # destroys, OOMs — every invalidation source the fast path must survive
-    pair = _Pair(capacity=512 * MB, sblock_va_budget=600 * MB)
+    pair = _Driver(capacity=512 * MB, va_budget=600 * MB)
     sizes = [
         2 * MB, 3 * MB, 8 * MB, 16 * MB, 17 * MB, 32 * MB, 64 * MB,
         100 * MB, 128 * MB,
@@ -320,25 +248,26 @@ def test_split_produces_chunk_views_not_copies():
 
 
 def test_dead_log_compaction_bounds_memory_and_stays_identical():
-    """The destroyed-block log is cleared (and stale plan caches dropped)
-    past DEAD_LOG_LIMIT, so memory stays O(live) — without any behaviour
-    change vs the force-disabled twin."""
-    pair = _Pair(capacity=2 * GB, sblock_va_budget=700 * MB)
-    pair.fast.DEAD_LOG_LIMIT = 3  # instance override: compact every 4 destroys
+    """Quarantined slots are recycled (and stale plan caches dropped)
+    past DEAD_LOG_LIMIT, so memory stays O(live) — with the invariant
+    checker holding after the compactions."""
+    pair = _Driver(capacity=2 * GB, va_budget=700 * MB)
+    pair.alloc.DEAD_LOG_LIMIT = 3  # instance override: compact every 4 destroys
     a, b = pair.malloc(256 * MB), pair.malloc(256 * MB)
     pair.free(a)
     pair.free(b)
     for i in range(12):  # fresh stitch + StitchFree destroy per cycle
         m = pair.malloc((512 - 2 * i) * MB)
         pair.free(m)
-    assert len(pair.fast._dead_refs) <= 4  # compacted at least twice
+    assert pair.alloc._vec_core.quarantined <= 4
+    assert pair.alloc.vec_counters["dead_compactions"] >= 2
     pair.check()
 
 
 def test_shell_generations_never_collide_across_lives():
     """A recycled shell's generation continues monotonically, so a stale
     holder stamp from the previous life can never read as active."""
-    a = GMLakeAllocator(VMMDevice(2 * GB), sblock_va_budget=700 * MB)
+    a = GMLakeAllocator(VMMDevice(2 * GB), va_budget=700 * MB)
     x, y = a.malloc(256 * MB), a.malloc(256 * MB)
     a.free(x)
     a.free(y)
