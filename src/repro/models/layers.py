@@ -321,24 +321,26 @@ def decode_attention_dense(
     window: Optional[int] = None,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Single-token decode over a dense KV cache (serve_step path)."""
+    """Single-token decode over a dense KV cache (serve_step path).
+
+    Grouped-query form: the query carries the group, so K and V are read
+    as stored and never copied per query head. Query head ``h = k*G + g``
+    attends with KV head ``k`` (``jnp.repeat``'s order); ``G == 1`` is MHA.
+    """
     b, _, h, d = q.shape
-    s = k_cache.shape[1]
+    s, n_kv = k_cache.shape[1], k_cache.shape[2]
     scale = (d**-0.5) if scale is None else scale
-    kf = _expand_kv(k_cache, h)
-    vf = _expand_kv(v_cache, h)
-    logits = jnp.einsum(
-        "bqhd,bshd->bhqs", q * scale, kf, preferred_element_type=jnp.float32
-    )
+    qg = (q * scale).reshape(b, n_kv, h // n_kv, d)
+    logits = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
+                        preferred_element_type=jnp.float32)
     pos = jnp.arange(s)[None, :]
     valid = pos < lengths[:, None]
     if window is not None:
         valid &= pos > (lengths[:, None] - 1 - window)
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqs,bshd->bqhd", p, vf, preferred_element_type=jnp.float32).astype(
-        q.dtype
-    )
+    o = jnp.einsum("bkgs,bskd->bkgd", p, v_cache, preferred_element_type=jnp.float32)
+    return o.reshape(b, 1, h, d).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -72,6 +72,82 @@ def test_flash_attention_fwd_bwd_vs_dense(name, fkw, rkw, heads):
 
 
 # ---------------------------------------------------------------------------
+# dense decode attention: grouped read == expanded (jnp.repeat) reference
+# ---------------------------------------------------------------------------
+
+
+def expanded_decode_ref(q, k, v, lengths, window=None):
+    """f32 decode attention with K and V repeated to every query head."""
+    h, d = q.shape[2], q.shape[3]
+    kf = jnp.repeat(k, h // k.shape[2], axis=2)
+    vf = jnp.repeat(v, h // v.shape[2], axis=2)
+    s = jnp.einsum("bhd,bshd->bhs", q[:, 0] * d**-0.5, kf)
+    pos = jnp.arange(k.shape[1])[None, :]
+    valid = pos < lengths[:, None]
+    if window is not None:
+        valid &= pos > lengths[:, None] - 1 - window
+    s = jnp.where(valid[:, None], s, -1e30)
+    return jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(s, -1), vf)[:, None]
+
+
+DECODE_CASES = [  # name, H, KVH, D, window
+    ("mha", 4, 4, 32, None),
+    ("mha_window", 4, 4, 32, 5),
+    ("gqa3", 9, 3, 64, None),
+    ("gqa3_window", 9, 3, 64, 7),
+    ("danube", 32, 8, 120, None),
+    ("danube_window", 32, 8, 120, 16),
+    ("head_order", 8, 2, 16, None),
+]
+
+
+@pytest.mark.parametrize("name,h,kvh,d,window", DECODE_CASES,
+                         ids=[c[0] for c in DECODE_CASES])
+def test_decode_attention_dense_vs_expanded(name, h, kvh, d, window):
+    from repro.models import layers as L
+
+    b, s = 3, 48
+    lengths = jnp.array([1, 29, s])  # ragged: one token, part, full
+    q = jax.random.normal(jax.random.fold_in(KEY, 11), (b, 1, h, d))
+    k = jax.random.normal(jax.random.fold_in(KEY, 12), (b, s, kvh, d))
+    v = jax.random.normal(jax.random.fold_in(KEY, 13), (b, s, kvh, d))
+    if name == "head_order":  # KV head k holds the value k everywhere
+        v = jnp.broadcast_to(jnp.arange(kvh, dtype=jnp.float32)[:, None],
+                             (b, s, kvh, d))
+    out = L.decode_attention_dense(q, k, v, lengths, window=window)
+    assert out.shape == (b, 1, h, d)
+    ref = expanded_decode_ref(q, k, v, lengths, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    if name == "head_order":  # query head h = k*G + g reads KV head k
+        want = np.repeat(np.arange(kvh), h // kvh)
+        np.testing.assert_allclose(np.asarray(out)[:, 0, :, 0],
+                                   np.broadcast_to(want, (b, h)), atol=1e-6)
+
+
+def _eqn_shapes(jaxpr):
+    """Output shapes of every equation, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield from (tuple(o.aval.shape) for o in eqn.outvars)
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqn_shapes(inner)
+
+
+def test_decode_attention_dense_never_expands_kv():
+    from repro.models import layers as L
+
+    b, s, h, kvh, d = 2, 64, 8, 2, 16
+    jaxpr = jax.make_jaxpr(L.decode_attention_dense)(
+        jnp.zeros((b, 1, h, d)), jnp.zeros((b, s, kvh, d)),
+        jnp.zeros((b, s, kvh, d)), jnp.full((b,), s))
+    shapes = list(_eqn_shapes(jaxpr.jaxpr))
+    assert (b, 1, h, d) in shapes  # the walk reached the output
+    assert (b, s, h, d) not in shapes  # no K or V copied per query head
+
+
+# ---------------------------------------------------------------------------
 # mamba2: chunked SSD == sequential recurrence; decode == forward
 # ---------------------------------------------------------------------------
 
